@@ -25,10 +25,6 @@ from __future__ import annotations
 
 import argparse
 import functools
-import json
-import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -36,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.scipy.linalg import solve_triangular
 
-from benchmarks.common import emit, make_system, timeit
+from benchmarks.common import SRC, emit, make_system, run_child, timeit
 from repro import telemetry
 from repro.core import api, cholesky, lu
 
@@ -181,17 +177,17 @@ def run(sizes=(512, 1024), compile_sizes=(256, 512, 1024), nb=128):
 # --------------------------------------------------------------------------
 
 _SPMD_CHILD = r"""
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(ndev)d"
+import sys, json, time
 sys.path.insert(0, %(src)r)
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import lu
 
 n, nb, ndev = %(n)d, %(nb)d, %(ndev)d
 p = int(ndev ** 0.5)
 while ndev %% p: p -= 1
-mesh = jax.make_mesh((p, ndev // p), ("data", "model"))
+mesh = make_mesh((p, ndev // p), ("data", "model"))
 rng = np.random.default_rng(0)
 a = (rng.standard_normal((n, n)) + n * np.eye(n)).astype(np.float32)
 b = rng.standard_normal(n).astype(np.float32)
@@ -265,21 +261,12 @@ def run_spmd(device_counts=(1, 2, 4, 8), n=1024, nb=64):
     strong-scaling measurement needs (at n=512 the curve measures
     collective latency, not the factorization).
     """
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
     flops = 2 / 3 * n ** 3
     curve = []                      # (ndev, gflops) for the summary row
     for ndev in device_counts:
         code = _SPMD_CHILD % {"ndev": ndev, "n": n, "nb": nb,
-                              "src": os.path.abspath(src)}
-        proc = subprocess.run([sys.executable, "-c", code],
-                              capture_output=True, text=True, timeout=900)
-        line = [l for l in proc.stdout.splitlines()
-                if l.startswith("RESULT ")]
-        if not line:
-            emit("direct_spmd", f"lu_spmd_n{n}_ndev{ndev}", "FAIL", "",
-                 proc.stderr.strip()[-200:].replace(",", ";"))
-            continue
-        r = json.loads(line[0][len("RESULT "):])
+                              "src": SRC}
+        r = run_child(code, ndev)
         gflops = flops / r["t_factor"] / 1e9
         curve.append((ndev, gflops))
         g1 = curve[0][1] if curve[0][0] == 1 else None

@@ -19,16 +19,12 @@ Standalone:  PYTHONPATH=src python -m benchmarks.bench_eigls
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit, timeit
+from benchmarks.common import SRC, emit, run_child, timeit
 from repro.core import api, qr
 
 
@@ -79,17 +75,17 @@ def run(shapes=((2048, 256), (1024, 1024)), nb=128, ls_shape=(4096, 512),
 # --------------------------------------------------------------------------
 
 _SPMD_CHILD = r"""
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(ndev)d"
+import sys, json, time
 sys.path.insert(0, %(src)r)
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.eigls import tsqr
 
 m, n, ndev = %(m)d, %(n)d, %(ndev)d
 p = int(ndev ** 0.5)
 while ndev %% p: p -= 1
-mesh = jax.make_mesh((p, ndev // p), ("data", "model"))
+mesh = make_mesh((p, ndev // p), ("data", "model"))
 rng = np.random.default_rng(0)
 a = rng.standard_normal((m, n)).astype(np.float32)
 aj = jnp.asarray(a)
@@ -112,20 +108,11 @@ print("RESULT " + json.dumps({"t_factor": t, "err": res}))
 
 
 def run_spmd(device_counts=(1, 2, 4, 8), m=8192, n=256):
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
     flops = 2 * m * n * n - 2 / 3 * n ** 3
     for ndev in device_counts:
         code = _SPMD_CHILD % {"ndev": ndev, "m": m, "n": n,
-                              "src": os.path.abspath(src)}
-        proc = subprocess.run([sys.executable, "-c", code],
-                              capture_output=True, text=True, timeout=900)
-        line = [l for l in proc.stdout.splitlines()
-                if l.startswith("RESULT ")]
-        if not line:
-            emit("eigls_spmd", f"tsqr_m{m}_n{n}_ndev{ndev}", "FAIL", "",
-                 proc.stderr.strip()[-200:].replace(",", ";"))
-            continue
-        r = json.loads(line[0][len("RESULT "):])
+                              "src": SRC}
+        r = run_child(code, ndev)
         emit("eigls_spmd", f"tsqr_factor_m{m}_n{n}_ndev{ndev}",
              round(flops / r["t_factor"] / 1e9, 2), "gflops",
              f"wall={r['t_factor'] * 1e3:.1f}ms QR=A err={r['err']:.1e} "

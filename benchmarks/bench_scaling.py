@@ -13,19 +13,14 @@ first init).
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-
-from benchmarks.common import emit
+from benchmarks.common import SRC, emit, run_child
 
 _CHILD = r"""
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(ndev)d"
+import sys, json, time
 sys.path.insert(0, %(src)r)
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import krylov, api, dist, operator
 from repro.analysis import hlo as H
 import repro.analysis.roofline as R
@@ -33,7 +28,7 @@ import repro.analysis.roofline as R
 n = %(n)d
 p = int(%(ndev)d ** 0.5)
 while %(ndev)d %% p: p -= 1
-mesh = jax.make_mesh((p, %(ndev)d // p), ("data", "model"))
+mesh = make_mesh((p, %(ndev)d // p), ("data", "model"))
 rng = np.random.default_rng(0)
 a = (rng.standard_normal((n, n)) / n + 4 * np.eye(n)).astype(np.float32)
 b = rng.standard_normal(n).astype(np.float32)
@@ -75,19 +70,10 @@ print("RESULT " + json.dumps(out))
 
 
 def run(n: int = 2048, device_counts=(1, 2, 4, 8, 16)):
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
     results = {}
     for ndev in device_counts:
-        code = _CHILD % {"ndev": ndev, "n": n, "src": os.path.abspath(src)}
-        proc = subprocess.run([sys.executable, "-c", code],
-                              capture_output=True, text=True, timeout=900)
-        line = [l for l in proc.stdout.splitlines()
-                if l.startswith("RESULT ")]
-        if not line:
-            emit("scaling", f"ndev{ndev}", "FAIL", "",
-                 proc.stderr.strip()[-200:].replace(",", ";"))
-            continue
-        results[ndev] = json.loads(line[0][len("RESULT "):])
+        code = _CHILD % {"ndev": ndev, "n": n, "src": SRC}
+        results[ndev] = run_child(code, ndev)
 
     for method in ("cg", "lu"):
         if 1 not in results:

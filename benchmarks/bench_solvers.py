@@ -14,16 +14,12 @@ reduction tally in each note — the number that motivates s-step methods
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit, make_system, timeit
+from benchmarks.common import SRC, emit, make_system, run_child, timeit
 from repro import telemetry
 from repro.core import api
 
@@ -142,17 +138,17 @@ def run(sizes=(512, 1024), dtypes=("float32",)):
 # --------------------------------------------------------------------------
 
 _SPMD_CHILD = r"""
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(ndev)d"
+import sys, json, time
 sys.path.insert(0, %(src)r)
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import api, pblas
 
 n, ndev = %(n)d, %(ndev)d
 p = int(ndev ** 0.5)
 while ndev %% p: p -= 1
-mesh = jax.make_mesh((p, ndev // p), ("data", "model"))
+mesh = make_mesh((p, ndev // p), ("data", "model"))
 rng = np.random.default_rng(0)
 a = rng.standard_normal((n, n)).astype(np.float32)
 spd = (a @ a.T / n + 4 * np.eye(n)).astype(np.float32)
@@ -189,20 +185,11 @@ def run_spmd(device_counts=(1, 2, 4, 8), n=1024):
     the communication-avoiding claim as a counted number — and a
     ``scaling_efficiency`` field (t at 1 dev / (ndev * t at ndev)).
     """
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
     t1 = {}                               # method -> wall at 1 device
     for ndev in device_counts:
         code = _SPMD_CHILD % {"ndev": ndev, "n": n,
-                              "src": os.path.abspath(src)}
-        proc = subprocess.run([sys.executable, "-c", code],
-                              capture_output=True, text=True, timeout=900)
-        line = [l for l in proc.stdout.splitlines()
-                if l.startswith("RESULT ")]
-        if not line:
-            emit("solvers_spmd", f"ca_sweep_n{n}_ndev{ndev}", "FAIL", "",
-                 proc.stderr.strip()[-200:].replace(",", ";"))
-            continue
-        for method, r in json.loads(line[0][len("RESULT "):]).items():
+                              "src": SRC}
+        for method, r in run_child(code, ndev).items():
             if ndev == device_counts[0]:
                 t1[method] = r["t"]
             eff = (f" scaling_efficiency={t1[method] / (ndev * r['t']):.2f}"

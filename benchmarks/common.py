@@ -1,12 +1,35 @@
 """Shared benchmark utilities: timing, CSV emission, system builders."""
 from __future__ import annotations
 
+import json
+import os
+import subprocess
+import sys
 import time
 
 import jax
 import numpy as np
 
 ROWS: list[tuple] = []
+
+SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
+
+
+def run_child(code: str, ndev: int, timeout: float = 900) -> dict:
+    """Run one device-count sweep point in a fresh CPU process and return
+    its ``RESULT {json}`` line.  XLA fixes the device count at start-up,
+    so every count needs its own process; ``JAX_PLATFORMS=cpu`` keeps the
+    child off any accelerator the parent process holds (these rows are
+    CPU emulation).  A child that prints no result fails the section."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=timeout)
+    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")]
+    if not line:
+        raise RuntimeError(f"{ndev}-device child exited {proc.returncode}"
+                           f" with no result:\n{proc.stderr[-2000:]}")
+    return json.loads(line[0][len("RESULT "):])
 
 
 def emit(bench: str, name: str, value, unit: str, note: str = ""):

@@ -30,7 +30,6 @@ import argparse
 import csv
 import json
 import os
-import sys
 import traceback
 
 
@@ -56,6 +55,9 @@ def main(argv=None):
         if unknown:
             raise SystemExit(f"unknown sections {sorted(unknown)}; "
                              f"known: {sorted(known)}")
+
+    from repro import compile_cache
+    compile_cache.enable()
 
     from benchmarks import (bench_direct, bench_eigls, bench_local_accel,
                             bench_scaling, bench_serve, bench_solvers,

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import api, lu, pblas
+from repro.launch import mesh as mesh_lib
 
 n, s = 512, 4
 rng = np.random.default_rng(0)
@@ -27,7 +28,7 @@ spd = a @ a.T / n + 4 * np.eye(n)
 b = rng.standard_normal(n)
 sj, bj = jnp.asarray(spd), jnp.asarray(b)
 x_ref = np.linalg.solve(spd, b)
-mesh = jax.make_mesh((1, 1), ("data", "model"))
+mesh = mesh_lib.make_mesh((1, 1), ("data", "model"))
 
 # -- one reduction per s iterations, counted ------------------------------
 # counts tally at TRACE time (the loop body traces once), so they are the

@@ -3,12 +3,12 @@
 
     PYTHONPATH=src python examples/lstsq_eig.py
 """
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import api
 from repro.sparse import BSR, problems
+from repro.launch import mesh as mesh_lib
 
 # an overdetermined (m, n) system: least squares min ||b - A x||
 rng = np.random.default_rng(0)
@@ -28,7 +28,7 @@ x = solver(b)
 print(f"qr factorize  |x - x*| = {np.abs(np.asarray(x) - xo).max():.2e}")
 
 # distributed: communication-avoiding TSQR inside ONE shard_map
-mesh = jax.make_mesh((1, 1), ("data", "model"))
+mesh = mesh_lib.make_mesh((1, 1), ("data", "model"))
 x = api.solve(a, b, method="qr", engine="spmd", mesh=mesh)
 print(f"tsqr (spmd)   |x - x*| = {np.abs(np.asarray(x) - xo).max():.2e}")
 

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.core import api
 from repro.sparse import BSR, problems
+from repro.launch import mesh as mesh_lib
 
 # 5-point Laplacian on a 64×64 grid → n = 4096, five nonzeros per row
 nx = 64
@@ -56,7 +57,7 @@ print(f"cg wall: sparse {ts*1e3:.1f} ms vs dense {td*1e3:.1f} ms "
       f"({td/ts:.1f}x)")
 
 # distributed: block rows shard over the mesh row axis (engine='spmd')
-mesh = jax.make_mesh((1, 1), ("data", "model"))
+mesh = mesh_lib.make_mesh((1, 1), ("data", "model"))
 x = api.solve(bsr, b, method="cg", tol=1e-6, mesh=mesh, engine="spmd",
               precond="block_jacobi")
 err = float(np.linalg.norm(np.asarray(x) -

@@ -20,13 +20,13 @@ import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import telemetry
 from repro.core import api
 from repro.telemetry import report
+from repro.launch import mesh as mesh_lib
 
 n, nb = 1024, 64
 rng = np.random.default_rng(0)
@@ -35,7 +35,7 @@ spd = (a @ a.T / n + 4 * np.eye(n)).astype(np.float32)
 nonsym = (a + n * np.eye(n)).astype(np.float32)
 b = rng.standard_normal(n).astype(np.float32)
 sj, aj, bj = jnp.asarray(spd), jnp.asarray(nonsym), jnp.asarray(b)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
 
 with telemetry.session("profile") as sess:
     # local (ref) engine: classic vs communication-avoiding CG + direct

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core import api, lu
 from repro.resilience import abft, inject
+from repro.launch import mesh as mesh_lib
 
 n, nb = 256, 32
 rng = np.random.default_rng(0)
@@ -35,7 +36,7 @@ spd = jnp.asarray(g @ g.T / n + 4 * np.eye(n))
 gen = jnp.asarray(g + n * np.eye(n))
 b = jnp.asarray(rng.standard_normal(n))
 x_ref = np.linalg.solve(np.asarray(spd), np.asarray(b))
-mesh = jax.make_mesh((1, 1), ("data", "model"))
+mesh = mesh_lib.make_mesh((1, 1), ("data", "model"))
 
 # -- 1. an injected matvec NaN, classified and recovered ------------------
 with inject.inject(site="matvec", mode="nan") as ses:

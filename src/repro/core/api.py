@@ -65,6 +65,22 @@ ENGINES = ("gspmd", "spmd")
 # since the operator itself only exists inside the shard_map body)
 _SPMD_CAPS = frozenset({"matvec_t", "gram"})
 
+# Matrix products of every solve, factorization and substitution.  At
+# XLA's default TPU precision an f32 product is one bf16 pass, and f32
+# solves then fail HPL's residual check (docs/solvers.md, "Precision");
+# CPU and GPU compute f32 products in f32 either way.
+MATMUL_PRECISION = "highest"
+
+
+def _at_precision(fn: Callable) -> Callable:
+    """``fn`` traced and run under :data:`MATMUL_PRECISION` (the jit
+    caches key on it, so it holds for every trace the call makes)."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            return fn(*args, **kwargs)
+    return wrapped
+
 
 @dataclasses.dataclass(frozen=True)
 class SolverEntry:
@@ -247,6 +263,7 @@ def _with_fail_reason(result: SolveResult) -> SolveResult:
     return result._replace(info=info)
 
 
+@_at_precision
 def _solve_impl(a: jax.Array, b: jax.Array, *, method: str = "lu",
                 mesh=None, engine: str = "gspmd", backend: str = "ref",
                 block_size: int = 128, tol: float = 1e-6,
@@ -586,12 +603,13 @@ def make_executable(*, method: str = "lu", mode: str = "solve",
                          f"{tuple(n for n, e in sorted(_REGISTRY.items()) if e.factor is not None)}")
     fkw = dict(block_size=block_size, mesh=None, backend=backend)
     if mode == "factor":
-        factor = lambda a: entry.factor(a, **fkw)
+        factor = lambda a: _at_precision(entry.factor)(a, **fkw)
         return jax.jit(factor if batch is None else jax.vmap(factor))
-    apply = lambda s, b: entry.apply(s, b, **fkw)
+    apply = lambda s, b: _at_precision(entry.apply)(s, b, **fkw)
     return jax.jit(apply if batch is None else jax.vmap(apply))
 
 
+@_at_precision
 def _factorize_impl(a: jax.Array, *, method: str = "lu", mesh=None,
                     block_size: int = 128, backend: str = "ref",
                     engine: str = "gspmd", validate: bool = True,
@@ -633,7 +651,8 @@ def _factorize_impl(a: jax.Array, *, method: str = "lu", mesh=None,
             _abft.verify(state)           # raises FactorCorruption
         else:
             state = entry.spmd_factor(a, **fkw)
-        return functools.partial(entry.spmd_apply, state, **fkw)
+        return functools.partial(_at_precision(entry.spmd_apply), state,
+                                 **fkw)
     if entry.factor is None:
         raise ValueError(f"direct method {method!r} has no factor/apply "
                          f"split; methods with one: {with_split}")
@@ -643,13 +662,14 @@ def _factorize_impl(a: jax.Array, *, method: str = "lu", mesh=None,
             raise ValueError("batched solves are single-device (mesh=None)")
         kw = dict(block_size=block_size, mesh=None, backend=backend)
         state = jax.vmap(lambda A: entry.factor(A, **kw))(a)
-        return lambda b: jax.vmap(
-            lambda s, B: entry.apply(s, B, **kw))(state, b)
+        return _at_precision(lambda b: jax.vmap(
+            lambda s, B: entry.apply(s, B, **kw))(state, b))
     if mesh is not None:
         a = dist.shard_matrix(a, mesh)
     state = entry.factor(a, block_size=block_size, mesh=mesh, backend=backend)
-    return functools.partial(entry.apply, state, block_size=block_size,
-                             mesh=mesh, backend=backend)
+    return functools.partial(_at_precision(entry.apply), state,
+                             block_size=block_size, mesh=mesh,
+                             backend=backend)
 
 
 def factorize(a: jax.Array, *, method: str = "lu", mesh=None,
@@ -686,6 +706,7 @@ def factorize(a: jax.Array, *, method: str = "lu", mesh=None,
     return out
 
 
+@_at_precision
 def eigsolve(a, k: int = 6, *, which: str = "LA", method: str = "lanczos",
              mesh=None, backend: str = "ref", ncv=None, v0=None,
              tol: float = 1e-8, n=None, dtype=None, validate: bool = True):

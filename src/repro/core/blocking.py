@@ -38,11 +38,27 @@ def check_backend_name(backend: str) -> None:
         raise ValueError(f"unknown backend {backend!r}; expected {BACKENDS}")
 
 
+def pallas_float32(dtype) -> bool:
+    """Whether a ``backend="pallas"`` request at ``dtype`` runs the f32
+    kernels.  Mosaic lowers them for float32 only, so on a TPU any other
+    dtype raises rather than quietly running something else.  Off-TPU the
+    kernels are interpreted and the caller keeps its exact path for other
+    dtypes (float64 keeps f64 accuracy)."""
+    if dtype == jnp.float32:
+        return True
+    if jax.default_backend() == "tpu":
+        raise ValueError(f"backend='pallas' kernels are float32-only on "
+                         f"TPU; got {jnp.dtype(dtype).name} — cast to "
+                         "float32 or use backend='ref'")
+    return False
+
+
 def effective_backend(backend: str, dtype) -> str:
-    """The Pallas kernels cast to f32 and accumulate in f32; any other
-    dtype stays on the exact jnp reference path — the same silent-fallback
-    rule as the iterative ``DenseOperator`` (float64 keeps f64 accuracy)."""
-    return "ref" if backend == "pallas" and dtype != jnp.float32 else backend
+    """The direct path's backend after :func:`pallas_float32`: off-TPU a
+    non-f32 ``"pallas"`` request runs the jnp reference path."""
+    if backend == "pallas" and not pallas_float32(dtype):
+        return "ref"
+    return backend
 
 
 def choose_block(n: int, block_size: int) -> int:

@@ -16,8 +16,8 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.scipy.linalg import solve_triangular
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import blocking, dist, pblas
@@ -315,11 +315,11 @@ def cholesky_factor_spmd(a: jax.Array, *, block_size: int = 128, mesh=None,
         # checksum seed c0 = A·e (row sums), replicated — the cyclic
         # column permutation is storage-only, natural-order sums apply
         l_cyc, err = shard_map(body, mesh=mesh, in_specs=(spec, P()),
-                               out_specs=(spec, P()), check_rep=False)(
+                               out_specs=(spec, P()), check_vma=False)(
             a[:, lay.colperm], jnp.sum(a, axis=1))
         return CholeskySpmdState(lay, l_cyc, err)
     l_cyc = shard_map(body, mesh=mesh, in_specs=(spec,),
-                      out_specs=spec, check_rep=False)(a[:, lay.colperm])
+                      out_specs=spec, check_vma=False)(a[:, lay.colperm])
     return CholeskySpmdState(lay, l_cyc)
 
 

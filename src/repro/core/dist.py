@@ -92,8 +92,8 @@ def constrain_vector(x: jax.Array, mesh: Mesh) -> jax.Array:
 def single_device_mesh() -> Mesh:
     """A (1, 1) mesh over the first device — lets every code path that wants a
     mesh run unchanged on one CPU device (tests)."""
-    return jax.make_mesh((1, 1), (ROW_AXIS, COL_AXIS),
-                         devices=jax.devices()[:1])
+    from repro.launch.mesh import make_mesh
+    return make_mesh((1, 1), (ROW_AXIS, COL_AXIS), devices=jax.devices()[:1])
 
 
 def divisible(n: int, mesh: Mesh) -> bool:

@@ -46,9 +46,9 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.scipy.linalg import solve_triangular
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import blocking, dist, pblas
 from repro.resilience import inject
@@ -503,12 +503,12 @@ def lu_factor_spmd(a: jax.Array, *, block_size: int = 128, mesh=None,
         # sums apply
         lu_cyc, perm, err = shard_map(
             body, mesh=mesh, in_specs=(spec, P()),
-            out_specs=(spec, P(), P()), check_rep=False)(
+            out_specs=(spec, P(), P()), check_vma=False)(
             a[:, lay.colperm],
             jnp.stack([jnp.sum(a, axis=1), jnp.sum(a, axis=0)]))
         return LuSpmdState(lay, lu_cyc, perm, err)
     lu_cyc, perm = shard_map(body, mesh=mesh, in_specs=(spec,),
-                             out_specs=(spec, P()), check_rep=False)(
+                             out_specs=(spec, P()), check_vma=False)(
         a[:, lay.colperm])
     return LuSpmdState(lay, lu_cyc, perm)
 

@@ -34,10 +34,10 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
-from repro.core import dist, pblas
+from repro.core import blocking, dist, pblas
 from repro.core import precond as precond_mod
 from repro.resilience import inject
 from repro.telemetry import comm as telem_comm
@@ -123,8 +123,9 @@ class LinearOperator:
 
 class DenseOperator(LinearOperator):
     """Global arrays on one device.  ``backend="pallas"`` fuses the update
-    and the pipelined reduction into single memory passes (float32 only;
-    other dtypes silently use the jnp reference path)."""
+    and the pipelined reduction into single memory passes (float32 only:
+    another dtype raises on a TPU and uses the jnp reference path
+    off-TPU — :func:`repro.core.blocking.pallas_float32`)."""
 
     has_transpose = True
 
@@ -161,7 +162,7 @@ class DenseOperator(LinearOperator):
         return m @ w
 
     def _fusable(self, v):
-        return self.backend == "pallas" and v.dtype == jnp.float32
+        return self.backend == "pallas" and blocking.pallas_float32(v.dtype)
 
     def update(self, x, r, p, ap, alpha):
         if self._fusable(x):
@@ -350,7 +351,7 @@ def spmd_run(body, mesh, row: str, in_specs: tuple, *operands):
     if armed:
         out_specs += (P(), P())      # residual ring + iters_to_tol (repl.)
     f = shard_map(body, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_rep=False)
+                  out_specs=out_specs, check_vma=False)
     from repro.core.krylov import SolveResult
     out = f(*operands)
     x, iters, res, conv, code, fail_iter = out[:6]

@@ -22,17 +22,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import dist
 from repro.resilience import inject
 from repro.telemetry import comm as _telem_comm
-
-# ``pvary`` only exists on JAX versions with varying-manual-axes tracking;
-# on older releases replication bookkeeping is implicit and it is a no-op.
-_pvary = getattr(jax.lax, "pvary", None) or (lambda x, axes: x)
-
 
 # --------------------------------------------------------------------------
 # Collective counters.  Tallied at TRACE time: every solver loop here is a
@@ -207,7 +202,7 @@ def bcast_local(x: jax.Array, src, d, axes) -> jax.Array:
 
 def _wrap(mesh: Mesh, body, in_specs, out_specs, check_vma: bool = True):
     return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
+                     check_vma=check_vma)
 
 
 def pmatvec_spmd(a: jax.Array, x: jax.Array, mesh: Mesh) -> jax.Array:
@@ -299,7 +294,7 @@ def pgemm_summa(a: jax.Array, b: jax.Array, mesh: Mesh,
             return c_acc + a_pan @ b_pan                 # local GEMM (MXU)
 
         c0 = jnp.zeros((m_loc, n_loc), jnp.promote_types(a_loc.dtype, b_loc.dtype))
-        c0 = _pvary(c0, (row, col))   # carry varies across the grid
+        c0 = jax.lax.pcast(c0, (row, col), to="varying")  # carry varies
         return jax.lax.fori_loop(0, steps, step, c0)
 
     return _wrap(mesh, body, (P(row, col), P(row, col)), P(row, col))(a, b)

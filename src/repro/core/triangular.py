@@ -20,9 +20,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.scipy.linalg import solve_triangular
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import blocking, dist, pblas
 from repro.telemetry import comm as telem_comm
@@ -42,12 +42,10 @@ def solve_lower_blocked(a: jax.Array, b: jax.Array, *,
     """Solve L y = b where L is the lower triangle of ``a``."""
     blocking.check_backend(backend, mesh)
     if blocking.effective_backend(backend, a.dtype) == "pallas":
-        # ONE inverse-based kernel launch; the auto wrapper applies the
-        # same pad policy itself, so don't pad twice
+        # ONE inverse-based kernel launch on the kernel's own tile; the
+        # auto wrapper applies the same pad policy itself
         from repro.kernels import trsm
-        return trsm.trsm_lower_auto(
-            a, b, unit_diagonal=unit_diagonal,
-            sb=blocking.choose_block(a.shape[0], block_size))
+        return trsm.trsm_lower_auto(a, b, unit_diagonal=unit_diagonal)
     n0 = b.shape[0]
     a, nb, n = blocking.pad_system(a, block_size)
     b = blocking.pad_rhs(b, n)
@@ -78,8 +76,7 @@ def solve_upper_blocked(a: jax.Array, b: jax.Array, *,
     blocking.check_backend(backend, mesh)
     if blocking.effective_backend(backend, a.dtype) == "pallas":
         from repro.kernels import trsm
-        return trsm.trsm_upper_auto(
-            a, b, sb=blocking.choose_block(a.shape[0], block_size))
+        return trsm.trsm_upper_auto(a, b)
     n0 = b.shape[0]
     a, nb, n = blocking.pad_system(a, block_size)
     b = blocking.pad_rhs(b, n)
@@ -199,7 +196,7 @@ def bsub_t_cyclic_local(a_loc, b, *, nb: int, procs: int, d, axes, gcol):
 
 def _cyclic_call(mesh, lay, body, a_cyc, bp):
     f = shard_map(body, mesh=mesh, in_specs=(lay.matrix_spec(), P()),
-                  out_specs=P(), check_rep=False)
+                  out_specs=P(), check_vma=False)
     return f(a_cyc, bp)
 
 

@@ -23,8 +23,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 BLOCK = 128
 
@@ -134,6 +134,6 @@ def compressed_pod_allreduce(grads, ef, mesh: Mesh, pspecs):
     specs = jax.tree.map(lambda s: s, pspecs,
                          is_leaf=lambda x: isinstance(x, P))
     f = shard_map(body, mesh=mesh, in_specs=((specs, specs),),
-                  out_specs=(specs, specs), check_rep=False,
-                  auto=frozenset(a for a in mesh.axis_names if a != "pod"))
+                  out_specs=(specs, specs), check_vma=False,
+                  axis_names=frozenset({"pod"}))
     return f((grads, ef))

@@ -35,8 +35,8 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import blocking, dist, pblas
 
@@ -116,7 +116,7 @@ def tsqr_factor_spmd(a: jax.Array, *, block_size: int = 128, mesh=None,
         return q1 @ mine, r2
 
     f = shard_map(body, mesh=mesh, in_specs=(P((row, col), None),),
-                  out_specs=(P((row, col), None), P()), check_rep=False)
+                  out_specs=(P((row, col), None), P()), check_vma=False)
     q_glob, r = f(a)
     return TsqrState(mesh=mesh, q=q_glob, r=r, m0=m0, n0=n0)
 
@@ -139,7 +139,7 @@ def tsqr_apply_spmd(state: TsqrState, b: jax.Array, *,
 
     qtb = shard_map(body, mesh=mesh,
                     in_specs=(P((row, col), None), P((row, col), None)),
-                    out_specs=P(), check_rep=False)(state.q, bv)
+                    out_specs=P(), check_vma=False)(state.q, bv)
     x = solve_upper_blocked(state.r, qtb, block_size=block_size,
                             backend=backend)
     return x[:, 0] if vec else x

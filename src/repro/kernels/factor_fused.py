@@ -31,9 +31,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.krylov_fused import _auto_interpret
 
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _iota2(shape, axis):
     return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
@@ -88,8 +85,8 @@ def lu_panel_update(a: jax.Array, linv: jax.Array, k, *, nb: int,
     interpret = _auto_interpret(interpret)
 
     params = {}
-    if _CompilerParams is not None and not interpret:
-        params["compiler_params"] = _CompilerParams(
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))
 
     return pl.pallas_call(
@@ -155,8 +152,8 @@ def cholesky_panel_update(a: jax.Array, linv: jax.Array, k, *, nb: int,
     interpret = _auto_interpret(interpret)
 
     params = {}
-    if _CompilerParams is not None and not interpret:
-        params["compiler_params"] = _CompilerParams(
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))
 
     return pl.pallas_call(

@@ -16,10 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# compat across pallas versions
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     k = pl.program_id(2)
@@ -48,8 +44,8 @@ def matmul(a: jax.Array, b: jax.Array, *, bm: int = 256, bn: int = 256,
     grid = (m // bm, n // bn, k // bk)
 
     params = {}
-    if _CompilerParams is not None and not interpret:
-        params["compiler_params"] = _CompilerParams(
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     return pl.pallas_call(

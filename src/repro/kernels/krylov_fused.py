@@ -19,9 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 _LANE = 128
 
 
@@ -68,8 +65,8 @@ def fused_cg_update(x: jax.Array, r: jax.Array, p: jax.Array, ap: jax.Array,
     alpha_arr = jnp.asarray([alpha], jnp.float32)
 
     params = {}
-    if _CompilerParams is not None and not interpret:
-        params["compiler_params"] = _CompilerParams(
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))
 
     vec_spec = pl.BlockSpec((br, _LANE), lambda i: (i, 0))
@@ -109,8 +106,8 @@ def _auto_interpret(interpret):
 
 def _pad_lanes(vs):
     # pad to a multiple of 8 rows (f32 min sublane tile), not just _LANE,
-    # so _pick_block_rows never degrades to skinny 1-row blocks when the
-    # row count is prime — zero-pads are exact for all these reductions.
+    # so _pick_block_rows always finds an aligned block — zero-pads are
+    # exact for all these reductions.
     n = vs[0].shape[0]
     pad = (-n) % (8 * _LANE)
     if pad:
@@ -119,10 +116,13 @@ def _pad_lanes(vs):
 
 
 def _pick_block_rows(rows: int, block_rows: int) -> int:
-    br = min(block_rows, rows)
-    while rows % br:
-        br -= 1
-    return br
+    """Largest multiple of 8 (the f32 sublane tile) that divides ``rows``
+    and is at most ``block_rows``; the whole of ``rows`` when none does
+    (a full-extent block needs no alignment)."""
+    for br in range(min(block_rows, rows) // 8 * 8, 0, -8):
+        if rows % br == 0:
+            return br
+    return rows
 
 
 def fused_cg_update_auto(x, r, p, ap, alpha, *, block_rows: int = 256,
@@ -170,8 +170,8 @@ def fused_pipelined_dots(r: jax.Array, u: jax.Array, w: jax.Array, *,
         return v.reshape(rows, _LANE)
 
     params = {}
-    if _CompilerParams is not None and not interpret:
-        params["compiler_params"] = _CompilerParams(
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))
 
     vec_spec = pl.BlockSpec((br, _LANE), lambda i: (i, 0))
@@ -233,8 +233,8 @@ def fused_gram(m: jax.Array, *, block_cols: int = 2048,
     n_steps = n // bc
 
     params = {}
-    if _CompilerParams is not None and not interpret:
-        params["compiler_params"] = _CompilerParams(
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))
 
     out = pl.pallas_call(
@@ -253,10 +253,11 @@ def fused_gram(m: jax.Array, *, block_cols: int = 2048,
 def fused_gram_auto(m: jax.Array, *, block_cols: int = 2048,
                     interpret: bool | None = None) -> jax.Array:
     """``fused_gram`` for arbitrary (k, n): zero-pads rows to the sublane
-    tile and columns to the lane tile (pads contribute exact 0 to every
-    Gram entry), slices the (k, k) result back, restores the dtype."""
+    tile and columns to 8 lane tiles, so the column chunk stays a few
+    lane tiles wide (pads contribute exact 0 to every Gram entry), slices
+    the (k, k) result back, restores the dtype."""
     k, n = m.shape
-    pad_k, pad_n = (-k) % 8, (-n) % _LANE
+    pad_k, pad_n = (-k) % 8, (-n) % (8 * _LANE)
     if pad_k or pad_n:
         m = jnp.pad(m, ((0, pad_k), (0, pad_n)))
     g = fused_gram(m, block_cols=block_cols,

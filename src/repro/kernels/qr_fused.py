@@ -33,9 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.krylov_fused import _auto_interpret
 
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _qr_kernel(k_ref, v_ref, t_ref, a_ref, o_ref, *, nb: int, bn: int):
     j = pl.program_id(0)
@@ -76,8 +73,8 @@ def qr_panel_update(a: jax.Array, v: jax.Array, t: jax.Array, k, *,
     interpret = _auto_interpret(interpret)
 
     params = {}
-    if _CompilerParams is not None and not interpret:
-        params["compiler_params"] = _CompilerParams(
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel",))
 
     return pl.pallas_call(

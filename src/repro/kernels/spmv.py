@@ -30,9 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _auto_interpret(interpret):
     return jax.default_backend() != "tpu" if interpret is None else interpret
@@ -78,8 +75,8 @@ def bsr_spmm(data: jax.Array, brick_map, col_map, valid,
     acc_dtype = jnp.float64 if data.dtype == jnp.float64 else jnp.float32
 
     params = {}
-    if _CompilerParams is not None and not interpret:
-        params["compiler_params"] = _CompilerParams(
+    if not interpret:
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

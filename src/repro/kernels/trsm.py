@@ -1,18 +1,22 @@
 """Inverse-based block triangular solve kernel (paper §2 step 2, TPU-native).
 
 GPU TRSV/TRSM is a latency-bound pointer chase; the TPU adaptation
-(DESIGN.md §2) converts the diagonal solves into GEMMs: the (sb × sb)
-diagonal sub-blocks of L are inverted once outside the kernel (tiny,
-vmapped), and the kernel performs the block forward-substitution
+converts the diagonal solves into GEMMs: the (sb × sb) diagonal
+sub-blocks of T are inverted once outside the kernel (tiny, vmapped), and
+the kernel performs the block substitution
 
-    X_i = Linv_ii @ (B_i - Σ_{j<i} L_ij X_j)
+    X_i = Tinv_ii @ (B_i - Σ_j T_ij X_j)     (j < i for L, j > i for U)
 
-entirely with MXU matmuls.  The running X lives in a VMEM scratch tile; the
-Σ over previous blocks is computed as one full-height matmul against the
-scratch (rows ≥ i are still zero), trading ~2× redundant flops for zero
-data-dependent control flow — the classic TPU bargain.
+entirely with MXU matmuls.
 
-Grid: one program per column tile of B (embarrassingly parallel).
+Grid: (column tile c of B, step s, step t).  Step s solves block row s
+of L, or block row nblk-1-s of U (back substitution runs the same loop
+with the block indices reversed).  T streams through VMEM one (sb, sb)
+tile at a time — it never lives there whole, so n is bounded by the
+solved column tile, not by T.  For t > s the T index map clamps to the
+diagonal tile, which the pipeline does not fetch again, so only T's own
+triangle is read from HBM.  The solved rows X (n, bc) stay in a VMEM
+scratch, in step order, that the later steps read.
 """
 from __future__ import annotations
 
@@ -24,36 +28,35 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.scipy.linalg import solve_triangular
 
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+# scoped-VMEM ceiling the kernel may request (v5e/v6e have 128 MiB);
+# the solved-rows scratch is n × bc × 4 bytes, so n ≲ 180k at bc = 128
+_VMEM_CAP = 100 << 20
 
 
-def _trsm_kernel(l_ref, linv_ref, b_ref, x_ref, scratch_ref, *,
-                 sb: int, n_blocks: int):
-    scratch_ref[...] = jnp.zeros_like(scratch_ref)
+def _trsm_kernel(t_ref, tinv_ref, b_ref, x_ref, xs_ref, acc_ref, *, sb: int):
+    s = pl.program_id(1)
+    t = pl.program_id(2)
 
-    def row_step(i, _):
-        # Σ_{j<i} L[i,:] @ X[:]: full-height matmul; X rows >= i are zero.
-        l_row = pl.load(l_ref, (pl.dslice(i * sb, sb), slice(None)))
-        contrib = jnp.dot(l_row, scratch_ref[...],
-                          preferred_element_type=jnp.float32)
-        b_i = pl.load(b_ref, (pl.dslice(i * sb, sb), slice(None)))
-        rhs = b_i.astype(jnp.float32) - contrib
-        linv_i = pl.load(linv_ref, (i, slice(None), slice(None)))
-        x_i = jnp.dot(linv_i.astype(jnp.float32), rhs,
+    @pl.when(t == 0)
+    def _init():
+        acc_ref[...] = b_ref[...].astype(jnp.float32)
+
+    @pl.when(t < s)
+    def _update():
+        x_t = xs_ref[pl.ds(pl.multiple_of(t * sb, sb), sb), :]
+        acc_ref[...] -= jnp.dot(t_ref[...].astype(jnp.float32), x_t,
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(t == s)
+    def _solve():
+        x_s = jnp.dot(tinv_ref[...], acc_ref[...],
                       preferred_element_type=jnp.float32)
-        pl.store(scratch_ref, (pl.dslice(i * sb, sb), slice(None)),
-                 x_i.astype(scratch_ref.dtype))
-        return 0
-
-    jax.lax.fori_loop(0, n_blocks, row_step, 0)
-    x_ref[...] = scratch_ref[...].astype(x_ref.dtype)
+        xs_ref[pl.ds(pl.multiple_of(s * sb, sb), sb), :] = x_s
+        x_ref[...] = x_s.astype(x_ref.dtype)
 
 
-def trsm_lower(l: jax.Array, b: jax.Array, *, unit_diagonal: bool = False,
-               sb: int = 128, bc: int = 256, interpret: bool = False
-               ) -> jax.Array:
-    """Solve L X = B (L lower-triangular (n, n), B (n, m))."""
+def _trsm(t: jax.Array, b: jax.Array, *, lower: bool, unit_diagonal: bool,
+          sb: int, bc: int, interpret: bool) -> jax.Array:
     n, m = b.shape
     sb = min(sb, n)
     bc = min(bc, m)
@@ -61,50 +64,67 @@ def trsm_lower(l: jax.Array, b: jax.Array, *, unit_diagonal: bool = False,
         raise ValueError(f"shapes {(n, m)} not tiled by {(sb, bc)}")
     n_blocks = n // sb
 
-    # invert the diagonal sub-blocks (tiny, once) — "local acceleration".
-    # One reshape + jnp.diagonal gather instead of a Python comprehension,
-    # so trace size is O(1) in n_blocks.
+    def blk(step):               # block row/column solved at a loop step
+        return step if lower else n_blocks - 1 - step
+
+    # invert the diagonal sub-blocks, in step order (tiny, once).  They
+    # are gathered with one dynamic slice each: a reshape-and-diagonal of
+    # T moves all n² entries, which at n = 16384 cost more than the solve
+    starts = blk(jnp.arange(n_blocks)) * sb
+    diag = jax.vmap(lambda k: jax.lax.dynamic_slice(t, (k, k), (sb, sb)))(
+        starts).astype(jnp.float32)
     ident = jnp.eye(sb, dtype=jnp.float32)
-    diag = jnp.diagonal(l.reshape(n_blocks, sb, n_blocks, sb),
-                        axis1=0, axis2=2)                    # (sb, sb, nblk)
-    diag = jnp.moveaxis(diag, -1, 0).astype(jnp.float32)     # (nblk, sb, sb)
-    linv = jax.vmap(lambda blk: solve_triangular(
-        blk, ident, lower=True, unit_diagonal=unit_diagonal))(diag)
+    tinv = jax.vmap(lambda d: solve_triangular(
+        d, ident, lower=lower, unit_diagonal=unit_diagonal))(diag)
 
     params = {}
-    if _CompilerParams is not None and not interpret:
-        params["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel",))
+    if not interpret:
+        # double-buffered T / Tinv / B / X tiles + the two f32 scratches
+        vmem = (2 * sb * sb * (t.dtype.itemsize + 4)
+                + 4 * sb * bc * b.dtype.itemsize + 4 * (n + sb) * bc)
+        if vmem > _VMEM_CAP:
+            raise ValueError(f"trsm at n={n}, bc={bc} needs "
+                             f"{vmem >> 20} MiB of VMEM (cap "
+                             f"{_VMEM_CAP >> 20} MiB)")
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(vmem + (4 << 20), 16 << 20))
 
     return pl.pallas_call(
-        functools.partial(_trsm_kernel, sb=sb, n_blocks=n_blocks),
-        grid=(m // bc,),
+        functools.partial(_trsm_kernel, sb=sb),
+        grid=(m // bc, n_blocks, n_blocks),
         in_specs=[
-            pl.BlockSpec((n, n), lambda j: (0, 0)),            # L (whole)
-            pl.BlockSpec((n_blocks, sb, sb), lambda j: (0, 0, 0)),  # Linv
-            pl.BlockSpec((n, bc), lambda j: (0, j)),           # B col tile
+            pl.BlockSpec((sb, sb), lambda c, s, u: (
+                blk(s), blk(jnp.minimum(u, s)))),                  # T tile
+            pl.BlockSpec((None, sb, sb), lambda c, s, u: (s, 0, 0)),  # Tinv
+            pl.BlockSpec((sb, bc), lambda c, s, u: (blk(s), c)),      # B
         ],
-        out_specs=pl.BlockSpec((n, bc), lambda j: (0, j)),
+        out_specs=pl.BlockSpec((sb, bc), lambda c, s, u: (blk(s), c)),
         out_shape=jax.ShapeDtypeStruct((n, m), b.dtype),
-        scratch_shapes=[pltpu.VMEM((n, bc), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, bc), jnp.float32),
+                        pltpu.VMEM((sb, bc), jnp.float32)],
         interpret=interpret,
         **params,
-    )(l, linv, b)
+    )(t, tinv, b)
+
+
+def trsm_lower(l: jax.Array, b: jax.Array, *, unit_diagonal: bool = False,
+               sb: int = 512, bc: int = 128, interpret: bool = False
+               ) -> jax.Array:
+    """Solve L X = B (L lower-triangular (n, n), B (n, m)).  Only the
+    lower triangle of ``l`` is read."""
+    return _trsm(l, b, lower=True, unit_diagonal=unit_diagonal, sb=sb,
+                 bc=bc, interpret=interpret)
 
 
 def trsm_upper(u: jax.Array, b: jax.Array, *, unit_diagonal: bool = False,
-               sb: int = 128, bc: int = 256, interpret: bool = False
+               sb: int = 512, bc: int = 128, interpret: bool = False
                ) -> jax.Array:
-    """Solve U X = B (U upper-triangular) with the SAME lower kernel.
-
-    Uses the reversal identity: with J the index-reversal permutation,
-    L' = J U J is lower triangular and U x = b  ⇔  L' (J x) = J b — two
-    cheap flips outside the kernel, zero new kernel code.
-    """
-    l = jnp.flip(u, (0, 1))
-    x = trsm_lower(l, jnp.flip(b, 0), unit_diagonal=unit_diagonal,
-                   sb=sb, bc=bc, interpret=interpret)
-    return jnp.flip(x, 0)
+    """Solve U X = B (U upper-triangular): the same kernel, stepping
+    through the block rows last to first.  Only the upper triangle of
+    ``u`` is read."""
+    return _trsm(u, b, lower=False, unit_diagonal=unit_diagonal, sb=sb,
+                 bc=bc, interpret=interpret)
 
 
 # --------------------------------------------------------------------------
@@ -142,21 +162,20 @@ def _trsm_auto(solve_fn, t: jax.Array, b: jax.Array, *, unit_diagonal: bool,
 
 
 def trsm_lower_auto(l: jax.Array, b: jax.Array, *,
-                    unit_diagonal: bool = False, sb: int = 128, bc: int = 256,
-                    interpret: bool | None = None) -> jax.Array:
+                    unit_diagonal: bool = False, sb: int = 512,
+                    bc: int = 128, interpret: bool | None = None
+                    ) -> jax.Array:
     """``trsm_lower`` for arbitrary shapes (zero/identity pad is exact)."""
     return _trsm_auto(trsm_lower, l, b, unit_diagonal=unit_diagonal,
                       sb=sb, bc=bc, interpret=interpret)
 
 
 def trsm_upper_auto(u: jax.Array, b: jax.Array, *,
-                    unit_diagonal: bool = False, sb: int = 128, bc: int = 256,
-                    interpret: bool | None = None) -> jax.Array:
-    """``trsm_upper`` for arbitrary shapes.
-
-    Pads *before* the reversal, so after the flip the identity pad is the
-    *leading* block of the lower system: its zero RHS rows solve first to
-    exact zeros and never feed the real rows.
-    """
+                    unit_diagonal: bool = False, sb: int = 512,
+                    bc: int = 128, interpret: bool | None = None
+                    ) -> jax.Array:
+    """``trsm_upper`` for arbitrary shapes.  The identity pad is the
+    last block, which back substitution solves first: its zero RHS rows
+    solve to exact zeros and never feed the real rows."""
     return _trsm_auto(trsm_upper, u, b, unit_diagonal=unit_diagonal,
                       sb=sb, bc=bc, interpret=interpret)

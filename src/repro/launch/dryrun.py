@@ -55,14 +55,6 @@ def _cost_analysis(compiled) -> dict:
     return ca
 
 
-def _ambient_mesh(mesh):
-    """Context manager installing `mesh` as the ambient mesh for bare-
-    PartitionSpec constraint resolution.  ``jax.set_mesh`` on new JAX;
-    the classic ``with mesh:`` resource env on older releases."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
 def pick_optimizer(cfg) -> str:
     """Adafactor for ≥50B-param configs (HBM capacity; see optim/adafactor)."""
     return "adafactor" if cfg.param_count() > 50e9 else "adamw"
@@ -73,7 +65,7 @@ def build_and_lower(arch: str, shape_name: str, mesh, *, opt_override=None):
     shape = SHAPES[shape_name]
     # ambient mesh: bare-PartitionSpec constraints inside model code
     # (runtime.mixer_cp) resolve against it during tracing
-    with _ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_name = opt_override or pick_optimizer(cfg)
             step_fn, sspecs, bspecs, opt = S.make_train_step(

@@ -21,9 +21,9 @@ import tempfile
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
@@ -31,6 +31,7 @@ from repro.core import api, pblas
 from repro.checkpoint import CheckpointManager
 from repro.models import registry
 from repro.train import sharding as sh, steps as S
+from repro.launch import mesh as mesh_lib
 
 
 def check(name, ok):
@@ -205,7 +206,7 @@ def test_compression(mesh):
         return compression.ring_allreduce_int8(xl.sum(0), "data")
 
     f = shard_map(body, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     got = np.asarray(f(jnp.asarray(x)))
     want = x.sum(axis=0)
     # int8 wire: error bounded by a few quant steps, measured against the
@@ -229,8 +230,8 @@ def test_checkpoint_elastic(mesh):
         mgr = CheckpointManager(d)
         mgr.save(1, state, blocking=True)
         # elastic: restore onto a smaller (2,2) mesh = "after losing hosts"
-        small = jax.make_mesh((2, 2), ("data", "model"),
-                              devices=jax.devices()[:4])
+        small = mesh_lib.make_mesh((2, 2), ("data", "model"),
+                                   devices=jax.devices()[:4])
         small_specs = S.state_specs(cfg, small)
         restored, step = mgr.restore(
             jax.eval_shape(lambda: state),
@@ -280,7 +281,7 @@ def test_resilience(mesh):
 
 
 def main():
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
     print(f"devices: {len(jax.devices())}", flush=True)
     test_solvers(mesh)
     test_ca_krylov(mesh)

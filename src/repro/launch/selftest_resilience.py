@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from repro.core import api, cholesky, dist, lu
 from repro.resilience import abft, inject
+from repro.launch import mesh as mesh_lib
 
 TOL = 1e-8
 
@@ -41,11 +42,11 @@ def check(name, ok):
 def make_mesh():
     ndev = len(jax.devices())
     if ndev >= 8:
-        return jax.make_mesh((4, 2), ("data", "model"),
-                             devices=jax.devices()[:8])
+        return mesh_lib.make_mesh((4, 2), ("data", "model"),
+                                  devices=jax.devices()[:8])
     if ndev >= 2:
-        return jax.make_mesh((2, 1), ("data", "model"),
-                             devices=jax.devices()[:2])
+        return mesh_lib.make_mesh((2, 1), ("data", "model"),
+                                  devices=jax.devices()[:2])
     return dist.single_device_mesh()
 
 

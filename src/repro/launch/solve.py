@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import api
 from repro.launch.mesh import solver_mesh
 from repro.resilience import inject
@@ -94,6 +95,7 @@ def main(argv=None):
                          "--checkpoint-dir)")
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     if args.dtype == "float64":
         jax.config.update("jax_enable_x64", True)
     spd = args.method in ("cholesky", "cg", "pipelined_cg", "ca_cg")

@@ -16,6 +16,7 @@ No-ops when there is no ambient mesh or the batch does not divide.
 from __future__ import annotations
 
 import contextlib
+import math
 
 import jax
 from jax.sharding import PartitionSpec as P
@@ -23,21 +24,32 @@ from jax.sharding import PartitionSpec as P
 _FORCED: bool | None = None
 
 
+def _ambient_mesh():
+    """The ambient abstract mesh, or None when there is none (or it has
+    no axes) — every helper below is a no-op then."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty or not mesh.axis_names:
+        return None
+    return mesh
+
+
+def _data_axes(mesh) -> tuple[tuple[str, ...], int]:
+    """The data-parallel axes (every axis but "model") and their size."""
+    dp = tuple(a for a in mesh.axis_names if a != "model")
+    return dp, math.prod(mesh.shape[a] for a in dp)
+
+
+def _constrain(x, *lead):
+    return jax.lax.with_sharding_constraint(
+        x, P(*lead, *([None] * (x.ndim - len(lead)))))
+
+
 def mixer_cp(x):
     """Reshard (B, S, d) activations to batch-over-ALL-axes, if possible."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty or not mesh.axis_names:
-            return x
-        total = 1
-        for a in mesh.axis_names:
-            total *= mesh.shape[a]
-        if x.shape[0] % total:
-            return x
-        spec = P(tuple(mesh.axis_names), *([None] * (x.ndim - 1)))
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (RuntimeError, ValueError, AttributeError):
+    mesh = _ambient_mesh()
+    if mesh is None or x.shape[0] % mesh.size:
         return x
+    return _constrain(x, tuple(mesh.axis_names))
 
 
 def tokens_shard(x):
@@ -45,59 +57,41 @@ def tokens_shard(x):
     dispatch's sort/gather otherwise pushes GSPMD into replicating tokens
     everywhere (measured: kimi-k2 attention ran at global batch per
     device)."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty or not mesh.axis_names:
-            return x
-        dp = tuple(a for a in mesh.axis_names if a != "model")
-        total = 1
-        for a in dp:
-            total *= mesh.shape[a]
-        if not dp or x.shape[0] % total:
-            return x
-        return jax.lax.with_sharding_constraint(
-            x, P(dp, *([None] * (x.ndim - 1))))
-    except (RuntimeError, ValueError, AttributeError):
+    mesh = _ambient_mesh()
+    if mesh is None:
         return x
+    dp, total = _data_axes(mesh)
+    if not dp or x.shape[0] % total:
+        return x
+    return _constrain(x, dp)
 
 
 def expert_shard(x):
     """(E, C, ...) expert-dispatch tensors: experts over "model" (EP),
     capacity rows over "data" — the expert einsums then run fully
     sharded instead of replicated."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return x
-        parts = [None] * x.ndim
-        if "model" in mesh.axis_names and x.shape[0] % mesh.shape["model"] == 0:
-            parts[0] = "model"
-        if "data" in mesh.axis_names and x.ndim > 1 \
-                and x.shape[1] % mesh.shape["data"] == 0:
-            parts[1] = "data"
-        if not any(parts):
-            return x
-        return jax.lax.with_sharding_constraint(x, P(*parts))
-    except (RuntimeError, ValueError, AttributeError):
+    mesh = _ambient_mesh()
+    if mesh is None:
         return x
+    parts = [None] * x.ndim
+    if "model" in mesh.axis_names and x.shape[0] % mesh.shape["model"] == 0:
+        parts[0] = "model"
+    if "data" in mesh.axis_names and x.ndim > 1 \
+            and x.shape[1] % mesh.shape["data"] == 0:
+        parts[1] = "data"
+    if not any(parts):
+        return x
+    return jax.lax.with_sharding_constraint(x, P(*parts))
 
 
 def replicate_heads(x):
     """(B, H, T, D) k/v: batch on DP, everything else replicated — one
     gather per layer instead of one per chunk-scan step."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty or not mesh.axis_names:
-            return x
-        dp = tuple(a for a in mesh.axis_names if a != "model")
-        total = 1
-        for a in dp:
-            total *= mesh.shape[a]
-        bspec = dp if (dp and x.shape[0] % total == 0) else None
-        return jax.lax.with_sharding_constraint(
-            x, P(bspec, *([None] * (x.ndim - 1))))
-    except (RuntimeError, ValueError, AttributeError):
+    mesh = _ambient_mesh()
+    if mesh is None:
         return x
+    dp, total = _data_axes(mesh)
+    return _constrain(x, dp if (dp and x.shape[0] % total == 0) else None)
 
 
 def seq_shard(x):
@@ -106,41 +100,18 @@ def seq_shard(x):
     1/TP per device and GSPMD turns the row-parallel all-reduce into
     reduce-scatter (+ all-gather at the next column-parallel matmul) —
     halving wire bytes per Megatron-SP.  No-op without an ambient mesh."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty or "model" not in mesh.axis_names:
-            return x
-        if x.ndim < 2 or x.shape[1] % mesh.shape["model"]:
-            return x
-        dp = tuple(a for a in mesh.axis_names if a != "model")
-        total = 1
-        for a in dp:
-            total *= mesh.shape[a]
-        bspec = dp if (dp and x.shape[0] % total == 0) else None
-        spec = P(bspec, "model", *([None] * (x.ndim - 2)))
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (RuntimeError, ValueError, AttributeError):
+    mesh = _ambient_mesh()
+    if mesh is None or "model" not in mesh.axis_names:
         return x
+    if x.ndim < 2 or x.shape[1] % mesh.shape["model"]:
+        return x
+    dp, total = _data_axes(mesh)
+    bspec = dp if (dp and x.shape[0] % total == 0) else None
+    return _constrain(x, bspec, "model")
 
 
-def mixer_cp_out(x):
-    """Reshard mixer output back to batch-over-DP (TP axes free again)."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty or not mesh.axis_names:
-            return x
-        dp = tuple(a for a in mesh.axis_names if a != "model")
-        if not dp:
-            return x
-        total = 1
-        for a in dp:
-            total *= mesh.shape[a]
-        if x.shape[0] % total:
-            return x
-        spec = P(dp, *([None] * (x.ndim - 1)))
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (RuntimeError, ValueError, AttributeError):
-        return x
+# reshard mixer output back to batch-over-DP (TP axes free again)
+mixer_cp_out = tokens_shard
 
 
 def use_pallas() -> bool:

@@ -19,8 +19,8 @@ options)`` — built through the cache-aware dispatch hook
 * :meth:`ExecutableCache.warm` — explicit prefill: builds each key's
   executable and drives one dummy solve through it, so the first real
   request hits jit's populated dispatch cache instead of a compile.
-* ``persistent_dir=`` — opt-in pass-through to JAX's on-disk
-  compilation cache, making warmth survive process restarts.
+* warmth across process restarts is JAX's persistent compilation
+  cache, which :func:`repro.compile_cache.enable` places.
 
 Also home to :func:`fingerprint`, the content hash the server's
 repeated-A fast path keys cached factorizations on.
@@ -115,7 +115,8 @@ class _LazyAOT:
     the observatory's HLO/memory analysis exactly once.  Later calls
     with the same arg signature dispatch straight to the compiled
     executable; a signature change (shouldn't happen — the key pins
-    shape and dtype) falls back to the plain jit fn, never fails."""
+    shape and dtype) goes through the plain jit fn.  A compile error
+    propagates to the caller."""
 
     __slots__ = ("_fn", "_compiled", "_sig", "_record")
 
@@ -137,12 +138,9 @@ class _LazyAOT:
             if sig == self._sig:
                 return self._compiled(*args)
             return self._fn(*args)
-        try:
-            t0 = time.perf_counter()
-            compiled = self._fn.lower(*args).compile()
-            compile_s = time.perf_counter() - t0
-        except Exception:               # un-AOT-able args: plain jit path
-            return self._fn(*args)
+        t0 = time.perf_counter()
+        compiled = self._fn.lower(*args).compile()
+        compile_s = time.perf_counter() - t0
         self._compiled, self._sig = compiled, sig
         try:
             self._record(compile_s, compiled)
@@ -156,9 +154,6 @@ class ExecutableCache:
 
     ``maxsize`` bounds the number of live executables (every one pins
     device buffers for its constants); eviction is least-recently-used.
-    ``persistent_dir`` additionally enables JAX's on-disk compilation
-    cache so XLA compiles survive restarts (best-effort — older jaxlibs
-    without the config flag just skip it).
 
     Entries are :class:`_LazyAOT` wrappers: the first call through a key
     compiles ahead of time, records per-key compile-seconds (visible in
@@ -166,8 +161,7 @@ class ExecutableCache:
     memory analysis once — so a serving process knows the modeled FLOPs
     and peak bytes of everything it keeps warm."""
 
-    def __init__(self, maxsize: int = 128,
-                 persistent_dir: str | None = None):
+    def __init__(self, maxsize: int = 128):
         if maxsize < 1:
             raise ValueError(f"maxsize={maxsize} must be >= 1")
         self.maxsize = maxsize
@@ -176,12 +170,6 @@ class ExecutableCache:
         self.misses = 0
         self.evictions = 0
         self.key_info: dict[CacheKey, dict] = {}
-        if persistent_dir is not None:
-            try:
-                jax.config.update("jax_compilation_cache_dir",
-                                  persistent_dir)
-            except Exception:
-                pass        # older jaxlib: in-process warmth only
 
     def __len__(self) -> int:
         return len(self._entries)

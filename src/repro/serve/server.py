@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import api
 from repro.core.krylov import SolveResult
 from repro.serve import bucket
@@ -106,6 +107,7 @@ class SolveServer:
             raise ValueError(f"max_batch={max_batch} must be >= 1")
         if max_delay_ms < 0:
             raise ValueError(f"max_delay_ms={max_delay_ms} must be >= 0")
+        compile_cache.enable()
         self.max_batch = max_batch
         self.max_delay_ms = max_delay_ms
         self.cache = cache if cache is not None \

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import dist, pblas
+from repro.core import blocking, dist, pblas
 from repro.core import operator as op_mod
 from repro.core import precond as precond_mod
 from repro.core.operator import DenseOperator, LinearOperator
@@ -62,11 +62,11 @@ class SparseOperator(DenseOperator):
             self._a_t = self.sparse.transpose()
 
     def _spmv_kernel_ok(self):
-        """Mosaic has no f64 lowering — on a real TPU, non-f32 silently
-        uses the jnp path (the repo-wide fallback rule); off-TPU the
-        kernel runs in interpret mode, which carries every dtype exactly."""
+        """Mosaic has no f64 lowering — on a TPU a non-f32 matrix raises
+        (:func:`repro.core.blocking.pallas_float32`); off-TPU the kernel
+        runs in interpret mode, which carries every dtype exactly."""
         return self.backend == "pallas" and (
-            self.sparse.dtype == jnp.float32
+            blocking.pallas_float32(self.sparse.dtype)
             or jax.default_backend() != "tpu")
 
     def _mv(self, v):

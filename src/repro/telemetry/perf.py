@@ -49,13 +49,19 @@ from repro.telemetry import metrics as metrics_mod
 # --------------------------------------------------------------------------
 
 # TPU per-chip datasheet peaks (dense bf16 matmul FLOP/s, HBM B/s, ICI
-# B/s per link) — matched by substring against device_kind
+# B/s per link), keyed by the exact ``device_kind`` jax reports (both of
+# the names each chip goes by).  Source: Google Cloud TPU documentation,
+# the per-generation "TPU v3" ... "TPU v6e" pages.  A TPU kind that is not
+# here is an error, never a guess.
+_V6E = dict(peak_flops=918e12, hbm_bw=1640e9, link_bw=100e9)
+_V5P = dict(peak_flops=459e12, hbm_bw=2765e9, link_bw=100e9)
+_V5E = dict(peak_flops=197e12, hbm_bw=819e9, link_bw=50e9)
 _TPU_TABLE = {
-    "v6e": dict(peak_flops=918e12, hbm_bw=1640e9, link_bw=100e9),
-    "v5p": dict(peak_flops=459e12, hbm_bw=2765e9, link_bw=100e9),
-    "v5e": dict(peak_flops=197e12, hbm_bw=819e9, link_bw=50e9),
-    "v4": dict(peak_flops=275e12, hbm_bw=1228e9, link_bw=50e9),
-    "v3": dict(peak_flops=123e12, hbm_bw=900e9, link_bw=70e9),
+    "TPU v6 lite": _V6E, "TPU v6e": _V6E,
+    "TPU v5": _V5P, "TPU v5p": _V5P,
+    "TPU v5 lite": _V5E, "TPU v5e": _V5E,
+    "TPU v4": dict(peak_flops=275e12, hbm_bw=1228e9, link_bw=50e9),
+    "TPU v3": dict(peak_flops=123e12, hbm_bw=900e9, link_bw=70e9),
 }
 
 
@@ -109,29 +115,28 @@ def _calibrate() -> tuple[float, float]:
 
 def detect(force: bool = False) -> MachineProfile:
     """The host's :class:`MachineProfile`, computed once and cached.
-    TPU kinds come from the datasheet table; CPU/GPU peaks are measured
-    (≈ tens of ms, once per process)."""
+    TPU kinds come from the datasheet table (an unknown kind raises);
+    CPU/GPU peaks are measured (≈ tens of ms, once per process)."""
     global _MACHINE
     if _MACHINE is not None and not force:
         return _MACHINE
     dev = jax.devices()[0]
-    platform = getattr(dev, "platform", "cpu")
-    kind = str(getattr(dev, "device_kind", "") or platform)
+    platform = dev.platform
+    kind = str(dev.device_kind or platform)
     if platform == "tpu":
-        peaks = next((p for tag, p in _TPU_TABLE.items()
-                      if tag in kind.lower()), _TPU_TABLE["v5e"])
-        _MACHINE = MachineProfile(kind, "tpu", source="table", **peaks)
+        if kind not in _TPU_TABLE:
+            raise ValueError(f"no datasheet peaks for TPU device_kind "
+                             f"{kind!r}; add it to perf._TPU_TABLE "
+                             f"(known: {sorted(_TPU_TABLE)})")
+        _MACHINE = MachineProfile(kind, "tpu", source="table",
+                                  **_TPU_TABLE[kind])
         return _MACHINE
-    try:
-        peak_flops, hbm_bw = _calibrate()
-        # single-host fabric: "the wire" is the memory system (cpu) or
-        # a conservative fraction of it (gpu NVLink-less default)
-        link_bw = hbm_bw if platform == "cpu" else hbm_bw / 4.0
-        _MACHINE = MachineProfile(kind, platform, peak_flops, hbm_bw,
-                                  link_bw, "calibrated")
-    except Exception:       # headless/odd backends: order-of-magnitude
-        _MACHINE = MachineProfile(kind, platform, 1e11, 5e10, 1e10,
-                                  "fallback")
+    peak_flops, hbm_bw = _calibrate()
+    # single-host fabric: "the wire" is the memory system (cpu) or a
+    # conservative fraction of it (gpu NVLink-less default)
+    link_bw = hbm_bw if platform == "cpu" else hbm_bw / 4.0
+    _MACHINE = MachineProfile(kind, platform, peak_flops, hbm_bw, link_bw,
+                              "calibrated")
     return _MACHINE
 
 

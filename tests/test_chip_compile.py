@@ -1,0 +1,102 @@
+"""Compile the main path's Pallas kernels at real widths for a described
+TPU v5e, without a chip attached.
+
+The TPU compiler is installed with jaxlib and compiles for a topology that
+is described rather than attached, so these tests catch what interpret
+mode cannot: tiles not aligned to the (8, 128) layout, and kernels that
+ask for more VMEM than the chip has.  Nothing runs; each test only asserts
+that the program compiles and that the kernel survived into it as a
+``tpu_custom_call``.
+
+The topology is described inside a module fixture, never at import time:
+only one process may load the TPU library, and the test workers all
+import this file.  Kernels are called with ``interpret=False`` explicitly
+because their ``interpret=None`` default keys on ``jax.default_backend()``,
+which is the CPU here.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+N = 16384          # the one-chip dense systems of chip_smoke.py
+NB = 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+F32 = jnp.float32
+
+
+@pytest.mark.parametrize("kind", ["lu", "cholesky"])
+def test_panel_update(one_chip, kind):
+    from repro.kernels import factor_fused
+    update = getattr(factor_fused, f"{kind}_panel_update")
+    _compile(lambda a, linv, k: update(a, linv, k, nb=NB, interpret=False),
+             one_chip, ((N, N), F32), ((NB, NB), F32), ((), jnp.int32))
+
+
+def test_gemm_rank_nb(one_chip):
+    from repro.kernels import gemm
+    _compile(lambda a, b: gemm.matmul(a, b, bm=NB, bn=NB, bk=NB,
+                                      interpret=False),
+             one_chip, ((N, NB), F32), ((NB, N), F32))
+
+
+@pytest.mark.parametrize("side", ["lower", "upper"])
+def test_trsm(one_chip, side):
+    """L streams through VMEM in tiles, so n = 16384 (a 1 GiB factor)
+    compiles; the whole-L window it replaced did not."""
+    from repro.kernels import trsm
+    solve = getattr(trsm, f"trsm_{side}_auto")
+    _compile(lambda t, b: solve(t, b, unit_diagonal=False, interpret=False),
+             one_chip, ((N, N), F32), ((N,), F32))
+
+
+@pytest.mark.parametrize("n", [N, 134144])
+def test_fused_cg_update(one_chip, n):
+    """134144 = 1048 rows of 128 lanes: no multiple of 8 below 256
+    divides it except 8, which is what the block must be."""
+    from repro.kernels import krylov_fused
+    _compile(lambda x, r, p, ap, alpha: krylov_fused.fused_cg_update_auto(
+        x, r, p, ap, alpha, interpret=False),
+        one_chip, *[((n,), F32)] * 4, ((), F32))
+
+
+def test_bsr_spmm_stencil(one_chip):
+    """A 24³ 7-point stencil in 128-row bricks: 108 block rows, at most
+    11 bricks each."""
+    from repro.kernels import spmv
+    nbr, max_blk = 108, 11
+    flat = ((nbr * max_blk,), jnp.int32)
+    _compile(lambda data, brick, col, valid, xb: spmv.bsr_spmm(
+        data, brick, col, valid, xb, nbr=nbr, interpret=False),
+        one_chip, ((nbr * max_blk, NB, NB), F32), flat, flat, flat,
+        ((nbr, NB, 1), F32))

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.distributed import compression as C
+from repro.launch import mesh as mesh_lib
 
 
 @pytest.mark.parametrize("n", [128, 1000, 4096])
@@ -46,12 +47,11 @@ def test_error_feedback_unbiased_over_time():
 
 def test_ring_allreduce_single_device():
     """axis size 1 → identity (no hops)."""
-    mesh = jax.make_mesh((1,), ("data",),
-                         devices=jax.devices()[:1])
-    from jax.experimental.shard_map import shard_map
+    mesh = mesh_lib.make_mesh((1,), ("data",), devices=jax.devices()[:1])
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     x = jnp.arange(256.0)
     f = shard_map(lambda v: C.ring_allreduce_int8(v, "data"),
                   mesh=mesh, in_specs=(P(),), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x))

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import api, blocking, cholesky, dist, lu, triangular
+from repro.launch import mesh as mesh_lib
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -30,8 +31,8 @@ def _mesh():
     CI's 8-virtual-device spmd job, (1, 1) in the default tier-1 run."""
     ndev = len(jax.devices())
     if ndev >= 8:
-        return jax.make_mesh((4, 2), ("data", "model"),
-                             devices=jax.devices()[:8])
+        return mesh_lib.make_mesh((4, 2), ("data", "model"),
+                                  devices=jax.devices()[:8])
     return dist.single_device_mesh()
 
 

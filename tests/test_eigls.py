@@ -20,6 +20,7 @@ import pytest
 from repro.core import api, blocking, dist, krylov, qr
 from repro.core.operator import DenseOperator
 from repro.sparse import BSR, problems
+from repro.launch import mesh as mesh_lib
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -42,8 +43,8 @@ def _rect(m, n, dtype=np.float64, seed=0):
 def _mesh():
     ndev = len(jax.devices())
     if ndev >= 8:
-        return jax.make_mesh((4, 2), ("data", "model"),
-                             devices=jax.devices()[:8])
+        return mesh_lib.make_mesh((4, 2), ("data", "model"),
+                                  devices=jax.devices()[:8])
     return dist.single_device_mesh()
 
 

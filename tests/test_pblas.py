@@ -49,7 +49,7 @@ def test_gspmd_engine(mesh1, rng):
 def test_collective_counts_kind_complete(mesh1):
     """The tally dict is kind-complete (every wrapper pre-seeded at 0)
     and the ppermute/all_to_all wrappers both tally and compute."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     import jax
 
@@ -63,7 +63,7 @@ def test_collective_counts_kind_complete(mesh1):
     with pblas.collective_counts() as c:
         out = jax.jit(shard_map(
             body, mesh=mesh1, in_specs=P("data"), out_specs=P("data"),
-            check_rep=False))(x)
+            check_vma=False))(x)
     assert set(c) == set(pblas.KINDS)
     assert c["ppermute"] == 1 and c["all_to_all"] == 1
     np.testing.assert_allclose(np.asarray(out), np.arange(8), rtol=1e-6)

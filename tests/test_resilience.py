@@ -22,6 +22,7 @@ import pytest
 
 from repro.core import api, cholesky, dist, lu, pblas
 from repro.resilience import abft, inject, monitor
+from repro.launch import mesh as mesh_lib
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -29,8 +30,8 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def _mesh():
     ndev = len(jax.devices())
     if ndev >= 8:
-        return jax.make_mesh((4, 2), ("data", "model"),
-                             devices=jax.devices()[:8])
+        return mesh_lib.make_mesh((4, 2), ("data", "model"),
+                                  devices=jax.devices()[:8])
     return dist.single_device_mesh()
 
 
