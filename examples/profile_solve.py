@@ -9,8 +9,10 @@ path the telemetry subsystem has:
 * the per-rank communication-volume table (the distributed LU's panel
   broadcast should be the top row: O(P · n · nb) bytes),
 * per-solve convergence records (iters_to_tol, residual histories),
-* ``profile_trace.json`` — Chrome-trace event JSON; load it at
-  https://ui.perfetto.dev,
+* ``profile_trace/`` — the ``jax.profiler`` trace of the session: the
+  device timeline with the ``repro.*`` spans on the same clock
+  (``.xplane.pb``) and ``perfetto_trace.json.gz``, which
+  https://ui.perfetto.dev loads,
 * ``TELEM_profile.json`` — the session JSON that
   ``python -m repro.telemetry.report`` renders.
 
@@ -37,7 +39,11 @@ b = rng.standard_normal(n).astype(np.float32)
 sj, aj, bj = jnp.asarray(spd), jnp.asarray(nonsym), jnp.asarray(b)
 mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
 
-with telemetry.session("profile") as sess:
+out_dir = os.path.dirname(os.path.abspath(__file__))
+trace_dir = os.path.join(out_dir, "profile_trace")
+telem_path = os.path.join(out_dir, "TELEM_profile.json")
+
+with telemetry.session("profile", profiler_dir=trace_dir) as sess:
     # local (ref) engine: classic vs communication-avoiding CG + direct
     api.solve(sj, bj, method="cg", tol=1e-6, return_info=True)
     api.solve(sj, bj, method="ca_cg", s=4, tol=1e-6, return_info=True)
@@ -54,14 +60,11 @@ with telemetry.session("profile") as sess:
     api.solve(aj, bj, method="lu", engine="spmd", mesh=mesh,
               block_size=nb, tol=1e-3, return_info=True)
 
-out_dir = os.path.dirname(os.path.abspath(__file__))
-trace_path = os.path.join(out_dir, "profile_trace.json")
-telem_path = os.path.join(out_dir, "TELEM_profile.json")
-sess.save_chrome_trace(trace_path)
 sess.save(telem_path)
 
 print(report.render(sess.to_dict()))
-print(f"chrome trace : {trace_path}  (load at https://ui.perfetto.dev)")
+print(f"trace dir    : {trace_dir}  (perfetto_trace.json.gz loads at "
+      "https://ui.perfetto.dev)")
 print(f"session json : {telem_path}  "
       "(render: python -m repro.telemetry.report)")
 

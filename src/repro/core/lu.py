@@ -34,6 +34,14 @@ Distribution — two engines, mirroring the iterative path:
 The per-column swap sequence is accumulated into a single row permutation
 applied as one gather per panel.
 
+Device scopes: each block step names its work with ``jax.named_scope`` —
+``lu.panel`` (the pivoted panel factorization), ``lu.pivot`` (the row
+gather and panel store), ``lu.update`` (the row-block TRSM and the
+rank-``nb`` trailing update), ``lu.bcast`` (the distributed panel
+broadcast) — and the substitutions are ``lu.fsub`` / ``lu.bsub``.  The
+scopes are metadata only: each lands in the ``op_name`` of the compiled
+program's instructions, so a profiler's op names map back to them.
+
 ``lu_factor`` returns (LU_packed, perm) with ``A[perm] = L @ U`` — i.e.
 ``perm`` is the accumulated row permutation (paper's ipiv, converted to
 permutation form).  When ``n`` is not a block multiple the factors are of
@@ -113,54 +121,57 @@ def lu_factor(a: jax.Array, block_size: int = 128, mesh=None,
         a, perm_total = carry
         k = s * nb
         # ---- panel: one pivoted factorization of the column block --------
-        colblk = jax.lax.dynamic_slice(a, (0, k), (n, nb))
-        if mesh is not None:
-            # gather the panel across process COLUMNS before the column
-            # loop (rows stay sharded): the nb-step pivoted factorization
-            # then runs on the row-sharded panel with small psum/argmax
-            # rounds — the paper's "panel on one process column" pattern
-            # (EXPERIMENTS.md §Perf solver hc3)
-            row_ax, _ = dist.solver_axes(mesh)
-            colblk = dist.constrain(colblk, mesh,
-                                    jax.sharding.PartitionSpec(row_ax, None))
-        pan, perm = _panel_factor(colblk, k)
-        pan = inject.tap("panel", pan, step=s)
+        with jax.named_scope("lu.panel"):
+            colblk = jax.lax.dynamic_slice(a, (0, k), (n, nb))
+            if mesh is not None:
+                # gather the panel across process COLUMNS before the column
+                # loop (rows stay sharded): the nb-step pivoted
+                # factorization then runs on the row-sharded panel with
+                # small psum/argmax rounds — the paper's "panel on one
+                # process column" pattern (EXPERIMENTS.md §Perf solver hc3)
+                row_ax, _ = dist.solver_axes(mesh)
+                colblk = dist.constrain(
+                    colblk, mesh, jax.sharding.PartitionSpec(row_ax, None))
+            pan, perm = _panel_factor(colblk, k)
+            pan = inject.tap("panel", pan, step=s)
         # one gather applies the whole panel's swap sequence (identity on
         # the already-factored rows) to L history + trailing matrix
-        a = jnp.take(a, perm, axis=0)
-        a = jax.lax.dynamic_update_slice(a, pan, (0, k))
-        perm_total = jnp.take(perm_total, perm)
+        with jax.named_scope("lu.pivot"):
+            a = jnp.take(a, perm, axis=0)
+            a = jax.lax.dynamic_update_slice(a, pan, (0, k))
+            perm_total = jnp.take(perm_total, perm)
         # ---- TRSM of the panel row block + rank-nb trailing update -------
-        l11 = jax.lax.dynamic_slice(a, (k, k), (nb, nb))
-        if backend == "pallas" and fuse_panel:
-            linv = solve_triangular(l11, jnp.eye(nb, dtype=a.dtype),
-                                    lower=True, unit_diagonal=True)
-            a = factor_fused.lu_panel_update(a, linv, k, nb=nb,
-                                             interpret=interp)
-        else:
-            rowblk = jax.lax.dynamic_slice(a, (k, 0), (nb, n))
-            if backend == "pallas":
-                u_full = trsm.trsm_lower(l11, rowblk, unit_diagonal=True,
-                                         sb=nb, bc=nb, interpret=interp)
+        with jax.named_scope("lu.update"):
+            l11 = jax.lax.dynamic_slice(a, (k, k), (nb, nb))
+            if backend == "pallas" and fuse_panel:
+                linv = solve_triangular(l11, jnp.eye(nb, dtype=a.dtype),
+                                        lower=True, unit_diagonal=True)
+                a = factor_fused.lu_panel_update(a, linv, k, nb=nb,
+                                                 interpret=interp)
             else:
-                u_full = solve_triangular(l11, rowblk, lower=True,
-                                          unit_diagonal=True)
-            u_keep = jnp.where(cols >= k + nb, u_full, rowblk)
-            a = jax.lax.dynamic_update_slice(a, u_keep.astype(a.dtype),
-                                             (k, 0))
-            # delayed rank-nb update — the Level-3 hot spot (masked full
-            # GEMM: inactive rows/cols contribute exact zeros)
-            l21 = jnp.where(rows >= k + nb,
-                            jax.lax.dynamic_slice(a, (0, k), (n, nb)), 0)
-            u12 = jnp.where(cols >= k + nb, u_full, 0).astype(a.dtype)
-            if backend == "pallas":
-                a = a - gemm.matmul(l21.astype(a.dtype), u12, bm=nb, bn=nb,
-                                    bk=nb, interpret=interp)
-            else:
-                a = a - l21 @ u12
-        a = inject.tap("trailing", a, step=s)
-        if mesh is not None:
-            a = dist.constrain_matrix(a, mesh)
+                rowblk = jax.lax.dynamic_slice(a, (k, 0), (nb, n))
+                if backend == "pallas":
+                    u_full = trsm.trsm_lower(l11, rowblk, unit_diagonal=True,
+                                             sb=nb, bc=nb, interpret=interp)
+                else:
+                    u_full = solve_triangular(l11, rowblk, lower=True,
+                                              unit_diagonal=True)
+                u_keep = jnp.where(cols >= k + nb, u_full, rowblk)
+                a = jax.lax.dynamic_update_slice(a, u_keep.astype(a.dtype),
+                                                 (k, 0))
+                # delayed rank-nb update — the Level-3 hot spot (masked full
+                # GEMM: inactive rows/cols contribute exact zeros)
+                l21 = jnp.where(rows >= k + nb,
+                                jax.lax.dynamic_slice(a, (0, k), (n, nb)), 0)
+                u12 = jnp.where(cols >= k + nb, u_full, 0).astype(a.dtype)
+                if backend == "pallas":
+                    a = a - gemm.matmul(l21.astype(a.dtype), u12, bm=nb,
+                                        bn=nb, bk=nb, interpret=interp)
+                else:
+                    a = a - l21 @ u12
+            a = inject.tap("trailing", a, step=s)
+            if mesh is not None:
+                a = dist.constrain_matrix(a, mesh)
         return a, perm_total
 
     a, perm_total = jax.lax.fori_loop(0, n // nb, step,
@@ -185,11 +196,14 @@ def lu_solve(lu: jax.Array, perm: jax.Array, b: jax.Array,
     """
     from repro.core.triangular import solve_lower_blocked, solve_upper_blocked
     n0 = b.shape[0]
-    bp = jnp.take(blocking.pad_rhs(b, lu.shape[0]), perm, axis=0)
-    y = solve_lower_blocked(lu, bp, unit_diagonal=True,
-                            block_size=block_size, mesh=mesh, backend=backend)
-    x = solve_upper_blocked(lu, y, block_size=block_size, mesh=mesh,
-                            backend=backend)
+    with jax.named_scope("lu.fsub"):
+        bp = jnp.take(blocking.pad_rhs(b, lu.shape[0]), perm, axis=0)
+        y = solve_lower_blocked(lu, bp, unit_diagonal=True,
+                                block_size=block_size, mesh=mesh,
+                                backend=backend)
+    with jax.named_scope("lu.bsub"):
+        x = solve_upper_blocked(lu, y, block_size=block_size, mesh=mesh,
+                                backend=backend)
     return x[:n0]
 
 
@@ -358,10 +372,12 @@ def lu_factor_spmd(a: jax.Array, *, block_size: int = 128, mesh=None,
                 pan, perm = _panel_factor(raw, s * nb)
                 return pack(pan, perm)
 
-            packed = jax.lax.cond(
-                d == owner, have,
-                lambda _: jnp.zeros((n, nb + 1), a_loc.dtype), None)
-            with telem_comm.site("lu_panel_bcast", iters=its):
+            with jax.named_scope("lu.panel"):
+                packed = jax.lax.cond(
+                    d == owner, have,
+                    lambda _: jnp.zeros((n, nb + 1), a_loc.dtype), None)
+            with jax.named_scope("lu.bcast"), \
+                    telem_comm.site("lu_panel_bcast", iters=its):
                 packed = pblas.bcast_local(packed, owner, d, axes)
             return (inject.tap("panel", packed[:, :nb], step=s, rank=d),
                     packed[:, nb].astype(jnp.int32))
@@ -379,58 +395,65 @@ def lu_factor_spmd(a: jax.Array, *, block_size: int = 128, mesh=None,
             owner2, t2 = lay.owner_of(s + 1), lay.slot_of(s + 1)
             valid = s + 1 < nblocks
             # -- swap gather on local columns; owner stores the panel ------
-            a_loc = jnp.take(a_loc, perm, axis=0)
-            perm_total = jnp.take(perm_total, perm)
-            a_loc = jnp.where(
-                d == owner,
-                jax.lax.dynamic_update_slice(a_loc, pan.astype(a_loc.dtype),
-                                             (0, t * nb)),
-                a_loc)
-            # -- TRSM of MY row block --------------------------------------
-            l11 = jax.lax.dynamic_slice(pan, (k, 0), (nb, nb))
-            rowblk = jax.lax.dynamic_slice(a_loc, (k, 0), (nb, nloc))
-            u_full = solve_triangular(l11, rowblk, lower=True,
-                                      unit_diagonal=True)
-            active = (gcol >= k + nb)[None, :]
-            a_loc = jax.lax.dynamic_update_slice(
-                a_loc, jnp.where(active, u_full, rowblk).astype(a_loc.dtype),
-                (k, 0))
-            l21 = jnp.where(rows_g >= k + nb, pan, 0).astype(a_loc.dtype)
-            # -- eager update of the NEXT panel's column (owner-only) ------
-            sel = (d == owner2) & valid
+            with jax.named_scope("lu.pivot"):
+                a_loc = jnp.take(a_loc, perm, axis=0)
+                perm_total = jnp.take(perm_total, perm)
+                a_loc = jnp.where(
+                    d == owner,
+                    jax.lax.dynamic_update_slice(
+                        a_loc, pan.astype(a_loc.dtype), (0, t * nb)),
+                    a_loc)
+            with jax.named_scope("lu.update"):
+                # -- TRSM of MY row block ----------------------------------
+                l11 = jax.lax.dynamic_slice(pan, (k, 0), (nb, nb))
+                rowblk = jax.lax.dynamic_slice(a_loc, (k, 0), (nb, nloc))
+                u_full = solve_triangular(l11, rowblk, lower=True,
+                                          unit_diagonal=True)
+                active = (gcol >= k + nb)[None, :]
+                a_loc = jax.lax.dynamic_update_slice(
+                    a_loc,
+                    jnp.where(active, u_full, rowblk).astype(a_loc.dtype),
+                    (k, 0))
+                l21 = jnp.where(rows_g >= k + nb, pan, 0).astype(a_loc.dtype)
+                # -- eager update of the NEXT panel's column (owner-only) --
+                sel = (d == owner2) & valid
 
-            def eager(_):
-                raw2 = jax.lax.dynamic_slice(a_loc, (0, t2 * nb), (n, nb))
-                u2 = jax.lax.dynamic_slice(
-                    u_full, (0, t2 * nb), (nb, nb)).astype(a_loc.dtype)
-                nxt = raw2 - l21 @ u2
-                if factor_next:
-                    return nxt, pack(*_panel_factor(nxt, k + nb))
-                return nxt
+                def eager(_):
+                    raw2 = jax.lax.dynamic_slice(a_loc, (0, t2 * nb),
+                                                 (n, nb))
+                    u2 = jax.lax.dynamic_slice(
+                        u_full, (0, t2 * nb), (nb, nb)).astype(a_loc.dtype)
+                    nxt = raw2 - l21 @ u2
+                    if factor_next:
+                        with jax.named_scope("lu.panel"):
+                            return nxt, pack(*_panel_factor(nxt, k + nb))
+                    return nxt
 
-            def skip(_):
-                z = jnp.zeros((n, nb), a_loc.dtype)
-                return (z, jnp.zeros((n, nb + 1), a_loc.dtype)) \
-                    if factor_next else z
+                def skip(_):
+                    z = jnp.zeros((n, nb), a_loc.dtype)
+                    return (z, jnp.zeros((n, nb + 1), a_loc.dtype)) \
+                        if factor_next else z
 
-            out = jax.lax.cond(sel, eager, skip, None)
-            nxt = out[0] if factor_next else out
-            a_loc = jnp.where(
-                sel, jax.lax.dynamic_update_slice(a_loc, nxt, (0, t2 * nb)),
-                a_loc)
-            # -- rest of the rank-nb update (in-flight columns excluded) ---
-            rest = active & ((gcol >= k + 2 * nb)[None, :] | ~valid)
-            u12 = jnp.where(rest, u_full, 0).astype(a_loc.dtype)
-            if backend == "pallas":
-                a_loc = a_loc - gemm.matmul(l21, u12, bm=nb, bn=nb, bk=nb,
-                                            interpret=interp)
-            else:
-                a_loc = a_loc - l21 @ u12
-            a_loc = inject.tap("trailing", a_loc, step=s, rank=d)
+                out = jax.lax.cond(sel, eager, skip, None)
+                nxt = out[0] if factor_next else out
+                a_loc = jnp.where(
+                    sel,
+                    jax.lax.dynamic_update_slice(a_loc, nxt, (0, t2 * nb)),
+                    a_loc)
+                # -- rest of the rank-nb update (in-flight columns excluded)
+                rest = active & ((gcol >= k + 2 * nb)[None, :] | ~valid)
+                u12 = jnp.where(rest, u_full, 0).astype(a_loc.dtype)
+                if backend == "pallas":
+                    a_loc = a_loc - gemm.matmul(l21, u12, bm=nb, bn=nb,
+                                                bk=nb, interpret=interp)
+                else:
+                    a_loc = a_loc - l21 @ u12
+                a_loc = inject.tap("trailing", a_loc, step=s, rank=d)
             base = (a_loc, perm_total)
             if not factor_next:
                 return base
-            with telem_comm.site("lu_panel_bcast", iters=nblocks):
+            with jax.named_scope("lu.bcast"), \
+                    telem_comm.site("lu_panel_bcast", iters=nblocks):
                 packed = pblas.bcast_local(out[1], owner2, d, axes)
             return base + (inject.tap("panel", packed[:, :nb],
                                       step=s + 1, rank=d),
@@ -523,7 +546,8 @@ def lu_apply_spmd(state: LuSpmdState, b: jax.Array, *, block_size: int = 128,
     lay = state.layout
     mesh = lay.mesh
     n0 = b.shape[0]
-    bp = jnp.take(blocking.pad_rhs(b, lay.n), state.perm, axis=0)
+    with jax.named_scope("lu.fsub"):
+        bp = jnp.take(blocking.pad_rhs(b, lay.n), state.perm, axis=0)
     bp, vec = tri._as_2d(bp)
     row, col = dist.solver_axes(mesh)
     q = mesh.shape[col]
@@ -531,8 +555,10 @@ def lu_apply_spmd(state: LuSpmdState, b: jax.Array, *, block_size: int = 128,
     def body(a_loc, b_rep):
         d = pblas.flat_index_local(row, col, q)
         kw = dict(nb=lay.nb, procs=lay.nprocs, d=d, axes=(row, col))
-        y = tri.fsub_cyclic_local(a_loc, b_rep, unit_diagonal=True, **kw)
-        return tri.bsub_cyclic_local(a_loc, y, **kw)
+        with jax.named_scope("lu.fsub"):
+            y = tri.fsub_cyclic_local(a_loc, b_rep, unit_diagonal=True, **kw)
+        with jax.named_scope("lu.bsub"):
+            return tri.bsub_cyclic_local(a_loc, y, **kw)
 
     x = tri._cyclic_call(mesh, lay, body, state.lu, bp)[:n0]
     return x[:, 0] if vec else x

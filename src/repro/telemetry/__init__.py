@@ -6,10 +6,10 @@ roofline-attributed solves, arm with ``session(..., perf=True)``).
 One entry point::
 
     from repro import telemetry
-    with telemetry.session("profile") as sess:
+    with telemetry.session("profile", profiler_dir="trace") as sess:
         x = api.solve(a, b, method="cg", mesh=mesh, engine="spmd")
     sess.save("TELEM_profile.json")            # repro.telemetry.report
-    sess.save_chrome_trace("trace.json")       # ui.perfetto.dev
+    # trace/: jax.profiler trace, repro.* spans beside the device ops
 
 Everything follows the zero-overhead-when-disarmed contract of
 ``resilience/inject.py``: with no session armed, no jaxpr changes by a

@@ -8,10 +8,13 @@ hit shows as a dispatch span with no ``compile_ms``) and ``execute``
 ``policy="resilient"`` ladder opens one ``attempt`` span per rung, so a
 recovered solve reads as a tree, not a mystery latency.
 
-Export: :meth:`Session.save` (JSON, the ``TELEM_*.json`` schema),
-:meth:`Session.save_chrome_trace` (Chrome-trace/Perfetto event JSON —
-load at https://ui.perfetto.dev), and ``profiler_dir=`` passes through
-to ``jax.profiler.trace`` for device-level timelines.
+Export: :meth:`Session.save` (JSON, the ``TELEM_*.json`` schema), and
+``profiler_dir=``, which records a ``jax.profiler`` trace of the session
+(``.xplane.pb`` plus ``perfetto_trace.json.gz`` for ui.perfetto.dev).
+Every span of an armed session is also a
+``jax.profiler.TraceAnnotation`` named ``repro.<span>``, so that trace
+holds ``repro.solve`` → ``repro.dispatch``/``repro.execute`` on the
+profiler's own clock, beside the device ops they launched.
 
 Zero overhead when disarmed: ``span()`` yields ``None`` after ONE module
 global check, and the solve path never calls ``block_until_ready`` it
@@ -144,28 +147,6 @@ class Session:
         with open(path, "w") as f:
             json.dump(self.to_dict(), f, indent=1, default=str)
 
-    def chrome_trace(self) -> dict:
-        """Chrome-trace/Perfetto "traceEvents" JSON (complete events)."""
-        events: list[dict] = []
-
-        def walk(sp: Span, tid: int):
-            ev = {"name": sp.name, "ph": "X", "pid": 0, "tid": tid,
-                  "ts": (sp.t0 - self.root.t0) * 1e6,
-                  "dur": sp.dur * 1e6,
-                  "args": {str(k): str(v) for k, v in sp.attrs.items()}}
-            if sp.events:
-                ev["args"]["compile_ms"] = f"{sum(e['ms'] for e in sp.events):.2f}"
-            events.append(ev)
-            for c in sp.children:
-                walk(c, tid)
-
-        walk(self.root, 0)
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-    def save_chrome_trace(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
-
 
 def _on_jax_event(event: str, duration_secs: float, **kw) -> None:
     """jax.monitoring listener: attach compile/lower durations to the
@@ -201,7 +182,9 @@ def session(name: str = "telemetry", *, histlen: int = 64,
     communication bytes, optionally the performance observatory
     (``perf=True`` — roofline-attributed solve records, see
     :mod:`repro.telemetry.perf`), and optionally a
-    ``jax.profiler.trace`` device timeline under ``profiler_dir``.
+    ``jax.profiler.trace`` under ``profiler_dir``: the device timeline
+    with the session's ``repro.*`` spans on the same clock, and a
+    ``perfetto_trace.json.gz`` of it.
     Yields the :class:`Session`; sessions nest (the inner one records
     until it closes)."""
     global _SESSION
@@ -217,7 +200,8 @@ def session(name: str = "telemetry", *, histlen: int = 64,
         if comm:
             s.comm = stack.enter_context(comm_mod.capture())
         if profiler_dir is not None:
-            stack.enter_context(jax.profiler.trace(profiler_dir))
+            stack.enter_context(jax.profiler.trace(
+                profiler_dir, create_perfetto_trace=True))
         _SESSION = s
         try:
             yield s
@@ -268,14 +252,17 @@ def _disarm_comm():
 @contextlib.contextmanager
 def span(name: str, **attrs):
     """Open a named span under the live session (``None`` yielded — and
-    nothing recorded — when no session is armed)."""
+    nothing recorded — when no session is armed).  The span is also a
+    ``jax.profiler.TraceAnnotation`` named ``repro.<name>``, recorded
+    when a profiler trace is running."""
     s = _SESSION
     if s is None:
         yield None
         return
     sp = s._open(name, attrs)
     try:
-        yield sp
+        with jax.profiler.TraceAnnotation(f"repro.{name}"):
+            yield sp
     finally:
         s._close(sp)
 

@@ -1,8 +1,9 @@
 """Telemetry subsystem: the zero-overhead-when-disarmed contract
 (bitwise-identical jaxprs, collective-count parity), in-graph convergence
-histories, the uniform info schema, span trees + Chrome-trace export,
-per-site communication bytes, the metrics registry, and the report CLI."""
-import json
+histories, the uniform info schema, span trees and their place in the
+profiler's trace, per-site communication bytes, the metrics registry, and
+the report CLI."""
+import glob
 
 import jax
 import jax.numpy as jnp
@@ -146,18 +147,28 @@ def test_span_tree_and_solve_record(rng):
     assert rec["converged"] is True
 
 
-def test_chrome_trace_export(tmp_path, rng):
+def test_session_spans_land_in_the_profiler_trace(tmp_path, rng):
+    """An armed session with ``profiler_dir`` writes its spans into the
+    profiler's own trace (the file that holds the device ops), nested as
+    they ran, and a Perfetto copy of it."""
     a, b = _sys(24, rng, spd=True)
-    with telemetry.session("t") as sess:
+    with telemetry.session("t", profiler_dir=str(tmp_path)):
         api.solve(a, b, method="cg", tol=1e-5)
-    p = tmp_path / "trace.json"
-    sess.save_chrome_trace(str(p))
-    data = json.loads(p.read_text())
-    assert data["traceEvents"]
-    for ev in data["traceEvents"]:
-        assert ev["ph"] == "X"
-        assert {"name", "pid", "tid", "ts", "dur"} <= set(ev)
-    assert any(ev["name"] == "solve" for ev in data["traceEvents"])
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert glob.glob(str(tmp_path / "**" / "perfetto_trace.json.gz"),
+                     recursive=True)
+    spans = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    spans.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.end_ns))
+    (s0, s1), = spans["repro.solve"]
+    for child in ("repro.dispatch", "repro.execute"):
+        (c0, c1), = spans[child]
+        assert s0 <= c0 <= c1 <= s1, child
+    assert spans["repro.dispatch"][0][1] <= spans["repro.execute"][0][0]
 
 
 def test_span_disarmed_yields_none():
