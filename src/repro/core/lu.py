@@ -32,7 +32,10 @@ Distribution — two engines, mirroring the iterative path:
   ``fori_loop``.
 
 The per-column swap sequence is accumulated into a single row permutation
-applied as one gather per panel.
+applied as one gather per panel.  Every such gather promises its indices
+in bounds (:func:`_permute_rows`): a permutation of ``arange(n)`` never
+leaves the matrix, so the out-of-bounds fill of ``jnp.take``'s default
+mode, a select over the whole matrix on every step, has nothing to do.
 
 Device scopes: each block step names its work with ``jax.named_scope`` —
 ``lu.panel`` (the pivoted panel factorization), ``lu.pivot`` (the row
@@ -61,6 +64,18 @@ from jax.sharding import PartitionSpec as P
 from repro.core import blocking, dist, pblas
 from repro.resilience import inject
 from repro.telemetry import comm as telem_comm
+
+
+def _permute_rows(x: jax.Array, perm: jax.Array) -> jax.Array:
+    """``x[perm]`` for a permutation ``perm`` of ``arange(x.shape[0])``.
+
+    Every index is in bounds, so the gather is told so: ``jnp.take``'s
+    default ``mode="fill"`` would follow it with a select over all of
+    ``x`` that puts NaN in out-of-bounds rows, of which there are none.
+    ``x`` may be a NumPy array: a right-hand side that needs no padding
+    reaches ``lu_solve`` as the caller passed it.
+    """
+    return jnp.asarray(x).at[perm].get(mode="promise_in_bounds")
 
 
 def _panel_factor(pan: jax.Array, k):
@@ -137,9 +152,9 @@ def lu_factor(a: jax.Array, block_size: int = 128, mesh=None,
         # one gather applies the whole panel's swap sequence (identity on
         # the already-factored rows) to L history + trailing matrix
         with jax.named_scope("lu.pivot"):
-            a = jnp.take(a, perm, axis=0)
+            a = _permute_rows(a, perm)
             a = jax.lax.dynamic_update_slice(a, pan, (0, k))
-            perm_total = jnp.take(perm_total, perm)
+            perm_total = _permute_rows(perm_total, perm)
         # ---- TRSM of the panel row block + rank-nb trailing update -------
         with jax.named_scope("lu.update"):
             l11 = jax.lax.dynamic_slice(a, (k, k), (nb, nb))
@@ -197,7 +212,7 @@ def lu_solve(lu: jax.Array, perm: jax.Array, b: jax.Array,
     from repro.core.triangular import solve_lower_blocked, solve_upper_blocked
     n0 = b.shape[0]
     with jax.named_scope("lu.fsub"):
-        bp = jnp.take(blocking.pad_rhs(b, lu.shape[0]), perm, axis=0)
+        bp = _permute_rows(blocking.pad_rhs(b, lu.shape[0]), perm)
         y = solve_lower_blocked(lu, bp, unit_diagonal=True,
                                 block_size=block_size, mesh=mesh,
                                 backend=backend)
@@ -396,8 +411,8 @@ def lu_factor_spmd(a: jax.Array, *, block_size: int = 128, mesh=None,
             valid = s + 1 < nblocks
             # -- swap gather on local columns; owner stores the panel ------
             with jax.named_scope("lu.pivot"):
-                a_loc = jnp.take(a_loc, perm, axis=0)
-                perm_total = jnp.take(perm_total, perm)
+                a_loc = _permute_rows(a_loc, perm)
+                perm_total = _permute_rows(perm_total, perm)
                 a_loc = jnp.where(
                     d == owner,
                     jax.lax.dynamic_update_slice(
@@ -547,7 +562,7 @@ def lu_apply_spmd(state: LuSpmdState, b: jax.Array, *, block_size: int = 128,
     mesh = lay.mesh
     n0 = b.shape[0]
     with jax.named_scope("lu.fsub"):
-        bp = jnp.take(blocking.pad_rhs(b, lay.n), state.perm, axis=0)
+        bp = _permute_rows(blocking.pad_rhs(b, lay.n), state.perm)
     bp, vec = tri._as_2d(bp)
     row, col = dist.solver_axes(mesh)
     q = mesh.shape[col]

@@ -100,3 +100,21 @@ def test_bsr_spmm_stencil(one_chip):
         data, brick, col, valid, xb, nbr=nbr, interpret=False),
         one_chip, ((nbr * max_blk, NB, NB), F32), flat, flat, flat,
         ((nbr, NB, 1), F32))
+
+
+def test_lu_row_gather_has_no_fill(one_chip):
+    """The pivot gather promises its indices in bounds: no whole-matrix
+    select fills out-of-bounds rows after it, and the solve's temporaries
+    stay near three matrices (a fill's select would add a fourth)."""
+    import re
+    from repro.core import api
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=one_chip)
+            for s in ((N, N), (N,))]
+    compiled = jax.jit(lambda a, b: api.solve(
+        a, b, method="lu", block_size=NB)).lower(*args).compile()
+    fills = [line for line in compiled.as_text().splitlines()
+             if re.search(rf"= f32\[{N},{N}\]\S* select\(", line)
+             and "lu.pivot" in line]
+    assert not fills
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps <= 3.1 * N * N * 4

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import api, blocking, cholesky, lu, triangular
+from repro.core import api, blocking, cholesky, dist, lu, triangular
 
 
 def _system(n, spd=False, dtype=np.float32, seed=0):
@@ -57,6 +57,44 @@ def test_jaxpr_size_independent_of_n(factor):
             args += (jnp.zeros((n,), jnp.float32),)
         return _total_eqns(jax.make_jaxpr(factor)(*args).jaxpr)
     assert count(256) == count(1024)
+
+
+def _gather_modes(jaxpr):
+    modes = []
+    for eq in jaxpr.eqns:
+        if eq.primitive.name == "gather":
+            modes.append(eq.params["mode"])
+        for v in eq.params.values():
+            for s in v if isinstance(v, (list, tuple)) else (v,):
+                if hasattr(s, "eqns"):
+                    modes += _gather_modes(s)
+                elif hasattr(s, "jaxpr"):
+                    modes += _gather_modes(s.jaxpr)
+    return modes
+
+
+def _spmd_factor(a):
+    st = lu.lu_factor_spmd(a, block_size=128,
+                           mesh=dist.single_device_mesh())
+    return st.lu, st.perm
+
+
+@pytest.mark.parametrize("path,args", [
+    (functools.partial(lu.lu_factor, block_size=128), ("a",)),
+    (functools.partial(lu.lu_solve, block_size=128), ("a", "perm", "b")),
+    (_spmd_factor, ("a",)),
+], ids=["lu_factor", "lu_solve", "lu_factor_spmd"])
+def test_row_permutation_gathers_promise_in_bounds(path, args):
+    """Pivoting applies permutations of arange(n), which never leave the
+    matrix: no gather may carry the out-of-bounds fill (a select over all
+    of its output once lowered)."""
+    from jax.lax import GatherScatterMode
+    n = 512
+    avals = {"a": jnp.zeros((n, n), jnp.float32),
+             "perm": jnp.arange(n), "b": jnp.zeros((n,), jnp.float32)}
+    modes = _gather_modes(
+        jax.make_jaxpr(path)(*(avals[k] for k in args)).jaxpr)
+    assert set(modes) == {GatherScatterMode.PROMISE_IN_BOUNDS}
 
 
 # --------------------------------------------------------------------------
