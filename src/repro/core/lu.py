@@ -42,6 +42,10 @@ Device scopes: each block step names its work with ``jax.named_scope`` —
 gather and panel store), ``lu.update`` (the row-block TRSM and the
 rank-``nb`` trailing update), ``lu.bcast`` (the distributed panel
 broadcast) — and the substitutions are ``lu.fsub`` / ``lu.bsub``.  The
+distributed engine's change into its cyclic layout, once per
+factorization, is ``lu.distribute``: the column gather ``a[:, colperm]``,
+into which the partitioner folds the collectives that carry the 2-D
+blocks into the ``shard_map``'s column shards.  The
 scopes are metadata only: each lands in the ``op_name`` of the compiled
 program's instructions, so a profiler's op names map back to them.
 
@@ -534,6 +538,8 @@ def lu_factor_spmd(a: jax.Array, *, block_size: int = 128, mesh=None,
         return finish(jax.lax.fori_loop(0, nblocks, step, init), w)
 
     spec = lay.matrix_spec()
+    with jax.named_scope("lu.distribute"):
+        a_cyc = a[:, lay.colperm]
     if abft:
         # checksum seeds, replicated: c0 = A·e (row sums, the carried
         # column) and w = eᵀA (column sums, the exit product check) —
@@ -542,12 +548,12 @@ def lu_factor_spmd(a: jax.Array, *, block_size: int = 128, mesh=None,
         lu_cyc, perm, err = shard_map(
             body, mesh=mesh, in_specs=(spec, P()),
             out_specs=(spec, P(), P()), check_vma=False)(
-            a[:, lay.colperm],
+            a_cyc,
             jnp.stack([jnp.sum(a, axis=1), jnp.sum(a, axis=0)]))
         return LuSpmdState(lay, lu_cyc, perm, err)
     lu_cyc, perm = shard_map(body, mesh=mesh, in_specs=(spec,),
                              out_specs=(spec, P()), check_vma=False)(
-        a[:, lay.colperm])
+        a_cyc)
     return LuSpmdState(lay, lu_cyc, perm)
 
 

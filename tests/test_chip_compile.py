@@ -26,7 +26,7 @@ NB = 128
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -40,9 +40,14 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, sharding, *shapes):
@@ -118,3 +123,32 @@ def test_lu_row_gather_has_no_fill(one_chip):
     assert not fills
     temps = compiled.memory_analysis().temp_size_in_bytes
     assert temps <= 3.1 * N * N * 4
+
+
+def test_spmd_hpl_solve_fits_a_2x2_host(topo):
+    """The four-chip HPL cell's program: the spmd LU at n = 49152,
+    nb = 128 on a (2, 2) ``solver_mesh``, from the generator's 2-D
+    blocks, fits a v5e chip's 15.75 GiB (9.00 GiB of arguments and
+    temporaries when the cell was added), and the collectives of its
+    change into the cyclic layout carry the ``lu.distribute`` scope."""
+    import re
+    from jax.sharding import NamedSharding
+    from repro.core import api, dist
+    from repro.launch.mesh import solver_mesh
+    n = 49152
+    mesh = solver_mesh(topo.devices)
+    a = jax.ShapeDtypeStruct((n, n), F32, sharding=NamedSharding(
+        mesh, dist.matrix_spec(mesh)))
+    b = jax.ShapeDtypeStruct((n,), F32, sharding=NamedSharding(
+        mesh, dist.vector_spec(mesh)))
+    compiled = jax.jit(lambda a, b: api.solve(
+        a, b, method="lu", block_size=NB, mesh=mesh,
+        engine="spmd")).lower(a, b).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        <= 15.75 * 2**30
+    layout = [line for line in compiled.as_text().splitlines()
+              if re.search(r"= \S+ (all-gather|all-to-all|"
+                           r"collective-permute-start)\(", line)]
+    assert len(layout) >= 3
+    assert all("lu.distribute/" in line for line in layout), layout
