@@ -5,12 +5,29 @@ are replaced by a single rank-``nb`` update so the hot loop is a large GEMM
 — on TPU that is the MXU hot spot, optionally executed by the Pallas
 kernels (``backend="pallas"``).
 
-Block stepping is a fixed-shape ``lax.fori_loop``: every step operates on
-statically-shaped windows of the full matrix (masked panel, masked TRSM,
-masked rank-``nb`` trailing update — ScaLAPACK-style), so trace/compile
-cost is O(1) in ``n`` instead of the O(n / nb) of a Python-unrolled loop.
-The masked regions contribute exact zeros; the redundant flops run on the
-MXU at full rate — the classic TPU bargain (see DESIGN.md §2).
+Block stepping runs in a few ``lax.fori_loop`` stages
+(:func:`stage_bounds`), each on a static trailing window.  Stage ``i``
+runs block steps ``[s0, s1)`` on ``w = a[k0:, k0:]`` with ``k0 = s0·nb``;
+inside it every step works on statically-shaped windows of ``w`` (masked
+panel, masked TRSM, masked rank-``nb`` trailing update — ScaLAPACK-style),
+whose masked regions contribute exact zeros.  Rows above ``k0`` are final
+and the columns left of it are L history, which the stage's swaps only
+permute, so the stage applies its accumulated row permutation to
+``a[k0:, :k0]`` once at its end and writes the ``(n − k0, n)`` row band
+back in one update: the factors are bitwise those of one whole-matrix
+loop.  The row gather, the rank-``nb`` update and XLA's layout copy for
+the panel slice then pass over the window, not the whole matrix: summed
+over the steps, 0.42 of the area at ``n/nb = 224``.
+
+The first stage is the whole matrix and ends at the first block boundary
+where the remaining width is at most ``n/√2``.  A stage's loop holds about
+four window-sized buffers beside the matrix, ``1 + 4r²`` matrices of
+temporaries for a window ``r·n`` wide, so with ``r ≤ 1/√2`` no later stage
+holds more than the whole-matrix loop's three.  The rest is split into at
+most 15 equal stages, so trace/compile cost is O(stages), at most 16 loop
+bodies, where a Python-unrolled loop would be O(n / nb).  Systems of fewer
+than 32 block steps, and the gspmd ``mesh=`` path (a static window would
+cut across its 2-D block sharding), run one stage over the whole matrix.
 
 ``backend="pallas"`` executes the step body with the Pallas kernels: by
 default the fused panel-update kernel (TRSM + rank-nb GEMM in one
@@ -58,6 +75,7 @@ pads/slices the right-hand side transparently.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +86,11 @@ from jax.sharding import PartitionSpec as P
 from repro.core import blocking, dist, pblas
 from repro.resilience import inject
 from repro.telemetry import comm as telem_comm
+from repro.telemetry import metrics as telem_metrics
+from repro.telemetry import trace as telem_trace
+
+_MAX_STAGES = 16
+_MIN_STAGED_BLOCKS = 32
 
 
 def _permute_rows(x: jax.Array, perm: jax.Array) -> jax.Array:
@@ -122,6 +145,35 @@ def _panel_factor(pan: jax.Array, k):
     return jax.lax.fori_loop(0, nb, col_step, (pan, jnp.arange(n)))
 
 
+def stage_bounds(nblocks: int, mesh=None) -> tuple[int, ...]:
+    """Block-step boundaries ``(0, s1, ..., nblocks)`` of :func:`lu_factor`'s
+    stages: stage ``i`` runs steps ``[b[i], b[i+1])`` on the trailing window
+    from block ``b[i]``.
+
+    The first stage ends at the first boundary whose remaining width is at
+    most ``nblocks/√2`` (its window then holds no more memory than the
+    whole matrix's stage); the rest is split into equal stages, at most
+    ``_MAX_STAGES`` in all.  One stage with a ``mesh`` or below
+    ``_MIN_STAGED_BLOCKS`` block steps, where the whole-matrix passes a
+    window saves are short beside each step's fixed cost and every stage
+    is one more loop body to compile.
+    """
+    if mesh is not None or nblocks < _MIN_STAGED_BLOCKS:
+        return (0, nblocks)
+    s1 = nblocks - math.isqrt(nblocks * nblocks // 2)
+    rest = nblocks - s1
+    k = min(_MAX_STAGES - 1, rest)
+    return (0,) + tuple(s1 + rest * i // k for i in range(k + 1))
+
+
+def window_area_share(bounds: tuple[int, ...]) -> float:
+    """The share of one whole-matrix loop's area (``nblocks`` steps over
+    the whole matrix) that the stages' windows cover, summed over steps."""
+    nblocks = bounds[-1]
+    return sum((s1 - s0) * (nblocks - s0) ** 2
+               for s0, s1 in zip(bounds, bounds[1:])) / nblocks ** 3
+
+
 def lu_factor(a: jax.Array, block_size: int = 128, mesh=None,
               backend: str = "ref", fuse_panel: bool = True
               ) -> tuple[jax.Array, jax.Array]:
@@ -129,72 +181,100 @@ def lu_factor(a: jax.Array, block_size: int = 128, mesh=None,
     blocking.check_backend(backend, mesh)
     backend = blocking.effective_backend(backend, a.dtype)
     a, nb, n = blocking.pad_system(a, block_size)
-    rows = jnp.arange(n)[:, None]
-    cols = jnp.arange(n)[None, :]
+    bounds = stage_bounds(n // nb, mesh)
+    if telem_trace.active() is not None:
+        telem_metrics.gauge_set("lu.stages", len(bounds) - 1)
+        telem_metrics.gauge_set("lu.window_area_share",
+                                window_area_share(bounds))
     if backend == "pallas":
         from repro.kernels import factor_fused, gemm, trsm
         from repro.kernels.krylov_fused import _auto_interpret
         interp = _auto_interpret(None)
 
-    def step(s, carry):
-        a, perm_total = carry
-        k = s * nb
-        # ---- panel: one pivoted factorization of the column block --------
-        with jax.named_scope("lu.panel"):
-            colblk = jax.lax.dynamic_slice(a, (0, k), (n, nb))
-            if mesh is not None:
-                # gather the panel across process COLUMNS before the column
-                # loop (rows stay sharded): the nb-step pivoted
-                # factorization then runs on the row-sharded panel with
-                # small psum/argmax rounds — the paper's "panel on one
-                # process column" pattern (EXPERIMENTS.md §Perf solver hc3)
-                row_ax, _ = dist.solver_axes(mesh)
-                colblk = dist.constrain(
-                    colblk, mesh, jax.sharding.PartitionSpec(row_ax, None))
-            pan, perm = _panel_factor(colblk, k)
-            pan = inject.tap("panel", pan, step=s)
-        # one gather applies the whole panel's swap sequence (identity on
-        # the already-factored rows) to L history + trailing matrix
-        with jax.named_scope("lu.pivot"):
-            a = _permute_rows(a, perm)
-            a = jax.lax.dynamic_update_slice(a, pan, (0, k))
-            perm_total = _permute_rows(perm_total, perm)
-        # ---- TRSM of the panel row block + rank-nb trailing update -------
-        with jax.named_scope("lu.update"):
-            l11 = jax.lax.dynamic_slice(a, (k, k), (nb, nb))
-            if backend == "pallas" and fuse_panel:
-                linv = solve_triangular(l11, jnp.eye(nb, dtype=a.dtype),
-                                        lower=True, unit_diagonal=True)
-                a = factor_fused.lu_panel_update(a, linv, k, nb=nb,
-                                                 interpret=interp)
-            else:
-                rowblk = jax.lax.dynamic_slice(a, (k, 0), (nb, n))
-                if backend == "pallas":
-                    u_full = trsm.trsm_lower(l11, rowblk, unit_diagonal=True,
-                                             sb=nb, bc=nb, interpret=interp)
-                else:
-                    u_full = solve_triangular(l11, rowblk, lower=True,
-                                              unit_diagonal=True)
-                u_keep = jnp.where(cols >= k + nb, u_full, rowblk)
-                a = jax.lax.dynamic_update_slice(a, u_keep.astype(a.dtype),
-                                                 (k, 0))
-                # delayed rank-nb update — the Level-3 hot spot (masked full
-                # GEMM: inactive rows/cols contribute exact zeros)
-                l21 = jnp.where(rows >= k + nb,
-                                jax.lax.dynamic_slice(a, (0, k), (n, nb)), 0)
-                u12 = jnp.where(cols >= k + nb, u_full, 0).astype(a.dtype)
-                if backend == "pallas":
-                    a = a - gemm.matmul(l21.astype(a.dtype), u12, bm=nb,
-                                        bn=nb, bk=nb, interpret=interp)
-                else:
-                    a = a - l21 @ u12
-            a = inject.tap("trailing", a, step=s)
-            if mesh is not None:
-                a = dist.constrain_matrix(a, mesh)
-        return a, perm_total
+    def stage(w, s0, s1):
+        """Steps ``[s0, s1)`` on the trailing window ``w`` from block
+        ``s0``; returns ``w`` and the stage's row permutation of it."""
+        m, k0 = w.shape[0], s0 * nb
+        rows = jnp.arange(m)[:, None]
+        cols = jnp.arange(m)[None, :]
 
-    a, perm_total = jax.lax.fori_loop(0, n // nb, step,
-                                      (a, jnp.arange(n)))
+        def step(s, carry):
+            a, perm_total = carry
+            k = s * nb - k0
+            # ---- panel: one pivoted factorization of the column block ----
+            with jax.named_scope("lu.panel"):
+                colblk = jax.lax.dynamic_slice(a, (0, k), (m, nb))
+                if mesh is not None:
+                    # gather the panel across process COLUMNS before the
+                    # column loop (rows stay sharded): the nb-step pivoted
+                    # factorization then runs on the row-sharded panel
+                    # with small psum/argmax rounds — the paper's "panel on
+                    # one process column" pattern
+                    row_ax, _ = dist.solver_axes(mesh)
+                    colblk = dist.constrain(
+                        colblk, mesh, jax.sharding.PartitionSpec(row_ax, None))
+                pan, perm = _panel_factor(colblk, k)
+                pan = inject.tap("panel", pan, step=s)
+            # one gather applies the whole panel's swap sequence (identity
+            # on the already-factored rows) to L history + trailing matrix
+            with jax.named_scope("lu.pivot"):
+                a = _permute_rows(a, perm)
+                a = jax.lax.dynamic_update_slice(a, pan, (0, k))
+                perm_total = _permute_rows(perm_total, perm)
+            # ---- TRSM of the panel row block + rank-nb trailing update ---
+            with jax.named_scope("lu.update"):
+                l11 = jax.lax.dynamic_slice(a, (k, k), (nb, nb))
+                if backend == "pallas" and fuse_panel:
+                    linv = solve_triangular(l11, jnp.eye(nb, dtype=a.dtype),
+                                            lower=True, unit_diagonal=True)
+                    a = factor_fused.lu_panel_update(a, linv, k, nb=nb,
+                                                     interpret=interp)
+                else:
+                    rowblk = jax.lax.dynamic_slice(a, (k, 0), (nb, m))
+                    if backend == "pallas":
+                        u_full = trsm.trsm_lower(l11, rowblk,
+                                                 unit_diagonal=True, sb=nb,
+                                                 bc=nb, interpret=interp)
+                    else:
+                        u_full = solve_triangular(l11, rowblk, lower=True,
+                                                  unit_diagonal=True)
+                    u_keep = jnp.where(cols >= k + nb, u_full, rowblk)
+                    a = jax.lax.dynamic_update_slice(
+                        a, u_keep.astype(a.dtype), (k, 0))
+                    # delayed rank-nb update — the Level-3 hot spot (masked
+                    # GEMM over the window: inactive rows/cols contribute
+                    # exact zeros)
+                    l21 = jnp.where(rows >= k + nb,
+                                    jax.lax.dynamic_slice(a, (0, k), (m, nb)),
+                                    0)
+                    u12 = jnp.where(cols >= k + nb, u_full, 0).astype(a.dtype)
+                    if backend == "pallas":
+                        a = a - gemm.matmul(l21.astype(a.dtype), u12, bm=nb,
+                                            bn=nb, bk=nb, interpret=interp)
+                    else:
+                        a = a - l21 @ u12
+                a = inject.tap("trailing", a, step=s)
+                if mesh is not None:
+                    a = dist.constrain_matrix(a, mesh)
+            return a, perm_total
+
+        return jax.lax.fori_loop(s0, s1, step, (w, jnp.arange(m)))
+
+    # the first stage's window is the whole matrix and its permutation the
+    # whole one so far
+    a, perm_total = stage(a, 0, bounds[1])
+    for s0, s1 in zip(bounds[1:], bounds[2:]):
+        k0 = s0 * nb
+        w, perm = stage(a[k0:, k0:], s0, s1)
+        # rows above k0 are final, and the stage's swaps only permuted the
+        # L history left of k0: apply them there once, and write the row
+        # band back whole
+        with jax.named_scope("lu.pivot"):
+            hist = _permute_rows(a[k0:, :k0], perm)
+            a = jax.lax.dynamic_update_slice(
+                a, jnp.concatenate([hist, w], axis=1), (k0, 0))
+            perm_total = jax.lax.dynamic_update_slice(
+                perm_total, _permute_rows(perm_total[k0:], perm), (k0,))
     return a, perm_total
 
 

@@ -13,12 +13,12 @@ from the pre-inverted (nb, nb) diagonal block (inverse-based TRSM, the same
 trick as :mod:`repro.kernels.trsm`) and immediately subtracts the rank-nb
 product, so the panel solve never leaves VMEM.
 
-The kernels are *masked*: they always run over the full (n, n) matrix with
-the step offset ``k`` passed as an SMEM scalar, so one launch geometry
-serves every step of the ``lax.fori_loop`` factorizations in
-:mod:`repro.core.lu` / :mod:`repro.core.cholesky` — trace/compile cost is
-O(1) in ``n`` (ScaLAPACK-style static windows), and the masked regions
-contribute exact zeros.
+The kernels are *masked*: they run over the whole (n, n) matrix they are
+given, with the step offset ``k`` passed as an SMEM scalar, so one launch
+geometry serves every step of a ``lax.fori_loop`` factorization in
+:mod:`repro.core.lu` / :mod:`repro.core.cholesky` (ScaLAPACK-style static
+windows; LU passes each stage's trailing window and the step's offset in
+it), and the masked regions contribute exact zeros.
 """
 from __future__ import annotations
 

@@ -37,7 +37,9 @@ Semantics worth knowing before writing a test:
   clean — exactly the transient-fault model the escalation policy
   recovers from.  A tap inside a loop body is corrupted for every
   runtime iteration of that attempt unless the site supplies a traced
-  ``step`` and the plan pins ``at_step``.
+  ``step`` and the plan pins ``at_step``.  The staged ``lu_factor``
+  (32 block steps or more) traces its body once per stage, the first
+  stage first: to pin a later step, give ``trips`` the stage count.
 * **Everything is logged.**  The armed session records each corruption
   (site, mode, tap hit index) so tests can assert the fault actually
   fired and recovery wasn't vacuous.

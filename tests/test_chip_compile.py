@@ -125,6 +125,69 @@ def test_lu_row_gather_has_no_fill(one_chip):
     assert temps <= 3.1 * N * N * 4
 
 
+def _lu_solve_compiled(one_chip, n):
+    from repro.core import api
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=one_chip)
+            for s in ((n, n), (n,))]
+    return jax.jit(lambda a, b: api.solve(
+        a, b, method="lu", block_size=NB)).lower(*args).compile()
+
+
+def _stage_loops(text):
+    """``{window: [body instruction lines]}`` for each ``while`` of the
+    compiled module that carries a square f32 matrix (its window)."""
+    import re
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    loops = {}
+    for line in text.splitlines():
+        if " while(" not in line:
+            continue
+        window = re.search(r"= \(.*?f32\[(\d+),\1\]", line)
+        if window:
+            body = re.search(r"body=%?([\w.\-]+)", line).group(1)
+            loops.setdefault(int(window.group(1)), []).extend(comps[body])
+    return loops
+
+
+def test_lu_stages_run_on_their_windows(one_chip):
+    """Past the first stage each loop of the one-chip LU works on its own
+    trailing window: the rank-nb update's product is there at every
+    window of ``lu.stage_bounds``, and no loop after the first holds a
+    whole-matrix layout copy (the panel slice's copy shrinks with the
+    window)."""
+    import re
+    from repro.core import lu
+    text = _lu_solve_compiled(one_chip, N).as_text()
+    windows = {N - s0 * NB for s0 in lu.stage_bounds(N // NB)[:-1]}
+    assert len(windows) == 16
+    products = {int(m.group(1)) for line in text.splitlines()
+                if "lu.update" in line
+                for m in [re.search(r"= f32\[(\d+),\1\]\S* "
+                                    r"(?:convolution|dot)\(", line)] if m}
+    assert products == windows
+    loops = _stage_loops(text)
+    assert windows <= set(loops)
+    whole = re.compile(rf"= f32\[{N},{N}\]\S* copy\(")
+    for window, body in loops.items():
+        if window < N:
+            assert not any(whole.search(line) for line in body), window
+
+
+def test_lu_at_32768_fits_one_chip(one_chip):
+    """The staged windows hold no more than the whole-matrix loop: at
+    n = 32768 the solve still compiles for one chip with temporaries
+    near three matrices (12.016 GiB before the stages)."""
+    compiled = _lu_solve_compiled(one_chip, 32768)
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps <= 3.1 * 32768 * 32768 * 4
+
+
 def test_spmd_hpl_solve_fits_a_2x2_host(topo):
     """The four-chip HPL cell's program: the spmd LU at n = 49152,
     nb = 128 on a (2, 2) ``solver_mesh``, from the generator's 2-D

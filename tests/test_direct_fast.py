@@ -83,7 +83,8 @@ def _spmd_factor(a):
     (functools.partial(lu.lu_factor, block_size=128), ("a",)),
     (functools.partial(lu.lu_solve, block_size=128), ("a", "perm", "b")),
     (_spmd_factor, ("a",)),
-], ids=["lu_factor", "lu_solve", "lu_factor_spmd"])
+    (functools.partial(lu.lu_factor, block_size=16), ("a",)),
+], ids=["lu_factor", "lu_solve", "lu_factor_spmd", "lu_factor_staged"])
 def test_row_permutation_gathers_promise_in_bounds(path, args):
     """Pivoting applies permutations of arange(n), which never leave the
     matrix: no gather may carry the out-of-bounds fill (a select over all
@@ -95,6 +96,98 @@ def test_row_permutation_gathers_promise_in_bounds(path, args):
     modes = _gather_modes(
         jax.make_jaxpr(path)(*(avals[k] for k in args)).jaxpr)
     assert set(modes) == {GatherScatterMode.PROMISE_IN_BOUNDS}
+
+
+# --------------------------------------------------------------------------
+# staged trailing windows: the schedule, and bitwise parity with one stage
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("nblocks", [1, 2, 8, 31, 32, 33, 64, 128, 224,
+                                     256, 1000])
+def test_stage_bounds(nblocks):
+    """Stages cover every block step once, in whole blocks (so each window
+    is a multiple of nb); the first window is at most n/√2 wide and the
+    first block boundary that is; the rest are equal; 16 stages at most;
+    one stage for small systems and with a mesh."""
+    b = lu.stage_bounds(nblocks)
+    assert b[0] == 0 and b[-1] == nblocks
+    assert all(type(s) is int for s in b)
+    assert all(s0 < s1 for s0, s1 in zip(b, b[1:]))
+    assert len(b) - 1 <= 16
+    assert lu.stage_bounds(nblocks, mesh=dist.single_device_mesh()) \
+        == (0, nblocks)
+    if nblocks < 32:
+        assert b == (0, nblocks)
+        return
+    w1 = nblocks - b[1]
+    assert 2 * w1 ** 2 <= nblocks ** 2 < 2 * (w1 + 1) ** 2
+    rest = [s1 - s0 for s0, s1 in zip(b[1:], b[2:])]
+    assert len(b) - 1 == 16 and max(rest) - min(rest) <= 1
+
+
+def _bounds(*b):
+    return lambda nblocks, mesh=None: b
+
+
+def _factor(a, nb, batch=False, **kw):
+    f = functools.partial(lu.lu_factor, block_size=nb, **kw)
+    return jax.jit(jax.vmap(f) if batch else f)(jnp.asarray(a))
+
+
+@pytest.mark.parametrize("n,nb,schedule,kw", [
+    (2048, 64, None, {}),
+    (1024, 64, _bounds(0, 4, 8, 12, 16), {}),
+    (1024, 64, _bounds(*range(17)), {}),
+    (1000, 64, _bounds(0, 5, 9, 16), {}),
+    (512, 64, _bounds(0, 2, 4, 6, 8), {"backend": "pallas"}),
+    (512, 64, _bounds(0, 3, 8), {"backend": "pallas", "fuse_panel": False}),
+    (1024, 64, _bounds(0, 5, 10, 16), {"batch": True}),
+], ids=["default-32-blocks", "4-stages", "16-stages", "padded-uneven",
+        "pallas-fused", "pallas-unfused", "vmap"])
+def test_staged_lu_bitwise_equals_one_stage(monkeypatch, n, nb, schedule,
+                                            kw):
+    """The staged factorization IS the one-stage loop: factors and pivots
+    bitwise equal.  (XLA's CPU product was seen to round differently in
+    windows whose rows are an odd multiple of 32; every window here is a
+    multiple of 64.)"""
+    rng = np.random.default_rng(n + nb)
+    shape = (3, n, n) if kw.get("batch") else (n, n)
+    a = rng.uniform(-0.5, 0.5, shape).astype(np.float32)
+    nblocks = -(-n // nb)
+    if schedule is None:
+        assert len(lu.stage_bounds(nblocks)) > 2
+    else:
+        monkeypatch.setattr(lu, "stage_bounds", schedule)
+    staged = _factor(a, nb, **kw)
+    monkeypatch.setattr(lu, "stage_bounds", _bounds(0, nblocks))
+    one = _factor(a, nb, **kw)
+    for x, y in zip(one, staged):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("n,stages,share", [(28672, 16, 0.4235),
+                                            (1024, 1, 1.0)])
+def test_lu_stage_gauges(n, stages, share):
+    """Traced under an armed telemetry session, ``lu_factor`` records its
+    schedule: the stage count and the share of the whole-matrix loop's
+    area that its windows pass over (tracing only; nothing runs)."""
+    from repro import telemetry
+    with telemetry.session("lu-stages", convergence=False, comm=False):
+        jax.make_jaxpr(functools.partial(lu.lu_factor, block_size=128))(
+            jax.ShapeDtypeStruct((n, n), jnp.float32))
+    assert telemetry.metrics.get_gauge("lu.stages") == stages
+    assert telemetry.metrics.get_gauge("lu.window_area_share") \
+        == pytest.approx(share, abs=5e-5)
+
+
+def test_lu_jaxpr_size_bounded_by_stages():
+    """Past the one-stage sizes the trace grows with the stage count, not
+    with n: 32 and 128 block steps both run 16 stages."""
+    def count(n):
+        return _total_eqns(jax.make_jaxpr(
+            functools.partial(lu.lu_factor, block_size=128))(
+                jax.ShapeDtypeStruct((n, n), jnp.float32)).jaxpr)
+    assert count(4096) == count(16384)
 
 
 # --------------------------------------------------------------------------
