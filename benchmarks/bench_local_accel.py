@@ -59,15 +59,6 @@ def run():
     emit("local_accel", "trsm_kernel_vs_ref_err",
          f"{float(jnp.max(jnp.abs(x_kernel - x_ref))):.2e}", "abs", "")
 
-    # flash attention
-    q = jax.random.normal(k1, (1, 4, 512, 64), jnp.float32)
-    kk = jax.random.normal(k2, (1, 2, 512, 64), jnp.float32)
-    v = jax.random.normal(k3, (1, 2, 512, 64), jnp.float32)
-    o_kernel = ops.flash_attention(q, kk, v, causal=True)
-    o_ref = ref.attention(q, kk, v, causal=True)
-    emit("local_accel", "attn_kernel_vs_ref_err",
-         f"{float(jnp.max(jnp.abs(o_kernel - o_ref))):.2e}", "abs", "")
-
     # fused Krylov update: traffic saving is the point (6n → 4n read+2n write)
     nvec = 1 << 16
     x0 = jax.random.normal(k1, (nvec,), jnp.float32)

@@ -13,7 +13,6 @@ Sections:
   sparse       — BSR SpMV GB/s + sparse-vs-dense CG wall time at matched n
   scaling      — Figs. 3/4: speedup vs node count (modeled v5e + emulated)
   local_accel  — §4 CUDA↔ATLAS ablation (Pallas↔jnp correctness + model)
-  train        — LM-stack step throughput + modeled full-scale cells
   serve        — solve server requests/sec + p50/p99 (cold vs warm cache,
                  repeated-A factor reuse)
 
@@ -46,8 +45,7 @@ def main(argv=None):
         os.path.dirname(__file__), "..", "experiments", "bench.csv"))
     args = ap.parse_args(argv)
     known = {"solvers", "solvers_spmd", "direct", "direct_spmd", "eigls",
-             "eigls_spmd", "sparse", "local_accel", "train", "scaling",
-             "serve"}
+             "eigls_spmd", "sparse", "local_accel", "scaling", "serve"}
     enabled = None
     if args.sections:
         enabled = {s.strip() for s in args.sections.split(",") if s.strip()}
@@ -61,7 +59,7 @@ def main(argv=None):
 
     from benchmarks import (bench_direct, bench_eigls, bench_local_accel,
                             bench_scaling, bench_serve, bench_solvers,
-                            bench_sparse, bench_train)
+                            bench_sparse)
     from benchmarks.common import ROWS
 
     failures = []
@@ -122,7 +120,6 @@ def main(argv=None):
             grids=(32,) if args.quick else (48, 64),
             nb=32 if args.quick else 64)
     section("local_accel", bench_local_accel.run)
-    section("train", bench_train.run)
     if args.quick:
         section("serve", bench_serve.run, sizes=(40, 60), wave=8,
                 warm_waves=2, repeats=3, distinct=3, max_batch=4)

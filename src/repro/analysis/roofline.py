@@ -12,9 +12,9 @@ performance observatory turns a measured wall time into a meaningful
 roofline-efficiency % on a CPU CI runner.
 
 Sources: FLOPs / traffic / collective payloads come from the while-aware
-HLO parser (``repro.analysis.hlo``) applied to the compiled dry-run
-artifact; ``compiled.cost_analysis()`` is recorded as a cross-check only
-(it counts scan bodies once — see hlo.py docstring).
+HLO parser (``repro.analysis.hlo``) applied to the compiled executable;
+``compiled.cost_analysis()`` is recorded as a cross-check only (it counts
+scan bodies once — see hlo.py docstring).
 
 Wire factors (bidirectional ring on the ICI torus; n = group size):
     all-reduce       2·(n−1)/n · payload
@@ -23,9 +23,9 @@ Wire factors (bidirectional ring on the ICI torus; n = group size):
     all-to-all       (n−1)/n · payload
     collective-permute  1 · payload
 
-MODEL_FLOPS (the "useful flops" yardstick) = 6·N·D for training (N =
-params, D = tokens; MoE: N_active), 2·N·D for inference forward — the
-ratio MODEL_FLOPS / HLO_FLOPs exposes remat/redundancy waste.
+``model_flops_global`` is the algorithm's own FLOP count (LU: 2/3·n³) —
+the ratio model FLOPs / HLO_FLOPs exposes redundant work (masked
+whole-matrix updates, padding).
 """
 from __future__ import annotations
 
@@ -55,7 +55,7 @@ class RooflineReport:
     hlo_flops: float
     hlo_bytes: float
     wire_bytes: float
-    model_flops_global: float            # 6·N·D (or 2·N·D serve)
+    model_flops_global: float            # the algorithm's FLOP count
     xla_flops: float = 0.0               # cost_analysis cross-check
     xla_bytes: float = 0.0
     collective_breakdown: dict = dataclasses.field(default_factory=dict)
@@ -96,25 +96,6 @@ class RooflineReport:
         return (self.model_flops_per_chip / self.hlo_flops
                 if self.hlo_flops else 0.0)
 
-    @property
-    def mfu_bound(self) -> float:
-        """Upper bound on MFU implied by the dominant term: useful flops
-        per second at the roofline, over peak."""
-        if self.t_bound == 0:
-            return 0.0
-        return (self.model_flops_per_chip / self.t_bound) / self.peak_flops
-
-    def row(self) -> dict:
-        return {
-            "name": self.name,
-            "t_compute_s": self.t_compute,
-            "t_memory_s": self.t_memory,
-            "t_collective_s": self.t_collective,
-            "bottleneck": self.bottleneck,
-            "useful_ratio": self.useful_ratio,
-            "mfu_bound": self.mfu_bound,
-        }
-
 
 def wire_bytes(cost: HloCost) -> tuple[float, dict]:
     total, detail = 0.0, {}
@@ -145,16 +126,3 @@ def roofline(name: str, cost: HloCost, *, chips: int,
         model_flops_global=model_flops_global,
         xla_flops=xla_flops, xla_bytes=xla_bytes,
         collective_breakdown=detail, **hw)
-
-
-def model_flops(cfg, shape) -> float:
-    """MODEL_FLOPS = 6·N·D train / 2·N·D forward / 2·N per decoded token."""
-    n = cfg.active_param_count()
-    if shape.kind == "train":
-        d = shape.global_batch * shape.seq_len
-        return 6.0 * n * d
-    if shape.kind == "prefill":
-        d = shape.global_batch * shape.seq_len
-        return 2.0 * n * d
-    # decode: one token per sequence per step
-    return 2.0 * n * shape.global_batch

@@ -1,5 +1,2 @@
-from repro.distributed.compression import (  # noqa: F401
-    quantize_int8, dequantize_int8, compressed_pod_allreduce,
-    ring_allreduce_int8)
 from repro.distributed.fault_tolerance import (  # noqa: F401
     HeartbeatMonitor, NodeFailure, run_with_recovery)

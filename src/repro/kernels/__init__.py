@@ -6,7 +6,6 @@
   one launch, masked for fori_loop block stepping)
 * ``qr_fused``     — fused QR compact-WY trailing update (Vᵀ A projection +
   rank-nb product in one launch, same masked fori_loop contract)
-* ``attention``    — flash attention fwd (GQA, causal, sliding window)
 * ``krylov_fused`` — fused CG/BiCGSTAB vector update + reduction
 * ``spmv``         — BSR SpMV/SpMM (scalar-prefetch brick gather +
   block-GEMM accumulate in one launch)

@@ -1,11 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-Dispatch policy (DESIGN.md §7): on TPU the compiled kernels run natively;
-on CPU (this container) they execute in ``interpret=True`` mode, which runs
-the kernel body in Python for correctness validation.  ``use_pallas=False``
-falls back to the pure-jnp oracle (``ref.py``) — that is also the path the
-512-device dry-run lowers, since Pallas TPU kernels cannot be compiled by
-the CPU backend.
+Dispatch policy: on TPU the compiled kernels run natively; on CPU they
+execute in ``interpret=True`` mode, which runs the kernel body in Python
+for correctness validation.  ``use_pallas=False`` falls back to the
+pure-jnp oracle (``ref.py``).
 
 This module is the "architecture independence" shim of the paper's level 2:
 callers never know which backend executed the math.
@@ -17,7 +15,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import attention as _attention
 from repro.kernels import gemm as _gemm
 from repro.kernels import krylov_fused as _krylov_fused
 from repro.kernels import ref as _ref
@@ -40,14 +37,6 @@ def trsm_lower(l, b, *, unit_diagonal: bool = False, use_pallas: bool = True,
         return _ref.trsm_lower(l, b, unit_diagonal=unit_diagonal)
     return _trsm.trsm_lower(l, b, unit_diagonal=unit_diagonal,
                             interpret=not _on_tpu(), **kw)
-
-
-def flash_attention(q, k, v, *, causal: bool = True, window=None,
-                    use_pallas: bool = True, **kw):
-    if not use_pallas:
-        return _ref.attention(q, k, v, causal=causal, window=window)
-    return _attention.flash_attention(q, k, v, causal=causal, window=window,
-                                      interpret=not _on_tpu(), **kw)
 
 
 def fused_cg_update(x, r, p, ap, alpha, *, use_pallas: bool = True, **kw):

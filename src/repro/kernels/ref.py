@@ -22,37 +22,6 @@ def trsm_lower(l: jax.Array, b: jax.Array, *, unit_diagonal: bool = False
     return solve_triangular(l, b, lower=True, unit_diagonal=unit_diagonal)
 
 
-def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-              causal: bool = True, window: int | None = None,
-              scale: float | None = None) -> jax.Array:
-    """Grouped-query softmax attention.
-
-    q: (B, Hq, Tq, D);  k, v: (B, Hkv, Tk, D) with Hq % Hkv == 0.
-    ``window``: sliding-window size (number of visible past positions,
-    including self) — ``None`` = full.
-    """
-    b, hq, tq, d = q.shape
-    hkv = k.shape[1]
-    group = hq // hkv
-    scale = (d ** -0.5) if scale is None else scale
-    kk = jnp.repeat(k, group, axis=1)
-    vv = jnp.repeat(v, group, axis=1)
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                        kk.astype(jnp.float32)) * scale
-    tk = k.shape[2]
-    qpos = jnp.arange(tq)[:, None] + (tk - tq)   # align ends (prefill/decode)
-    kpos = jnp.arange(tk)[None, :]
-    mask = jnp.ones((tq, tk), bool)
-    if causal:
-        mask &= kpos <= qpos
-    if window is not None:
-        mask &= kpos > qpos - window
-    logits = jnp.where(mask, logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", probs, vv.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
 def fused_cg_update(x: jax.Array, r: jax.Array, p: jax.Array,
                     ap: jax.Array, alpha) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One-pass CG vector update: x += α p; r -= α Ap; return <r,r> too."""

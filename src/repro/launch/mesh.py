@@ -1,12 +1,14 @@
 """Device meshes — the one place the library builds a ``jax.Mesh``.
 
-Single pod: 16×16 = 256 chips (v5e pod), axes ("data", "model").
-Multi-pod:  2×16×16 = 512 chips, axes ("pod", "data", "model") — the
-leading "pod" axis crosses DCN and is used for data parallelism (plus the
-compressed gradient reduction in repro.distributed.compression).
+``solver_mesh`` is the solvers' 2-D process grid over the devices at hand.
+The production meshes are what the compile dry-run
+(``repro.launch.dryrun``) lowers against on virtual devices:
+single pod 16×16 = 256 chips (v5e pod), axes ("data", "model");
+multi-pod 2×16×16 = 512 chips, axes ("pod", "data", "model"), the leading
+"pod" axis crossing DCN.
 
-Every mesh has Auto axes: the gspmd engine and the LM stack place data
-with ``with_sharding_constraint`` and leave the collectives to the XLA
+Every mesh has Auto axes: the gspmd engine places data with
+``with_sharding_constraint`` and leaves the collectives to the XLA
 partitioner, which Explicit axes (``jax.make_mesh``'s default since JAX
 0.7) refuse.  The shard_map engines are indifferent to the axis type.
 

@@ -1,12 +1,1 @@
-from repro.optim.adamw import adamw  # noqa: F401
-from repro.optim.adafactor import adafactor  # noqa: F401
-from repro.optim.schedules import cosine_schedule, wsd_schedule  # noqa: F401
 from repro.optim.second_order import cg_newton_step  # noqa: F401
-
-
-def get_optimizer(name: str, **kw):
-    if name == "adamw":
-        return adamw(**kw)
-    if name == "adafactor":
-        return adafactor(**kw)
-    raise ValueError(f"unknown optimizer {name!r}")

@@ -1,15 +1,15 @@
 """Second-order step via the paper's Krylov suite (solver-in-the-optimizer).
 
 The CUPLSS solvers are model-agnostic, matrix-free Krylov drivers — the
-natural place they appear inside an LM training framework is solving the
-damped Gauss-Newton/Hessian system
+natural place they appear inside a training loop is solving the damped
+Gauss-Newton/Hessian system
 
     (H + λ I) d = g
 
 with Hessian-vector products (``jax.jvp`` over ``jax.grad``) as the matvec.
 This is the paper's CG applied verbatim; it demonstrates the library
-composing with the training stack (see examples/cg_newton.py and
-tests/test_second_order.py).
+composing with a model written in plain ``jax.numpy`` (see
+examples/cg_newton.py and tests/test_second_order.py).
 """
 from __future__ import annotations
 
@@ -42,12 +42,12 @@ def cg_newton_step(loss_fn: Callable, params, batch, *, damping: float = 1e-3,
                    cg_tol: float = 1e-4, cg_iters: int = 20,
                    lr: float = 1.0, backtrack: int = 4):
     """One damped-Newton step: solve (H + λI) d = ∇L with the library CG,
-    then backtracking line search along d (LM losses are non-convex; an
+    then backtracking line search along d (training losses are non-convex; an
     indefinite H can make the raw CG direction an ascent direction).
 
     Returns (new_params, aux) with aux = {loss, cg_iters, residual, lr}.
     """
-    # NOTE: run this with an fp32 model (param_dtype/act_dtype float32) —
+    # NOTE: run this with float32 parameters and activations —
     # bf16 Hessian-vector products are quantization noise and destroy CG's
     # conjugacy (see tests/test_second_order.py).
     loss, g_tree = jax.value_and_grad(loss_fn)(params, batch)

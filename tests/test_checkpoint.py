@@ -1,5 +1,5 @@
 """Checkpointing: atomic commit, async save, bf16 round-trip, retention,
-restart determinism."""
+restart determinism of a checkpointed solve."""
 import os
 
 import jax
@@ -68,42 +68,26 @@ def test_structure_mismatch_raises(tmp_path):
 
 
 def test_restart_determinism(tmp_path):
-    """Train 4 steps straight == train 2, checkpoint, restore, train 2."""
-    from repro.configs import get_config
-    from repro.configs.base import ShapeConfig
-    from repro.data import make_pipeline
-    from repro.models import registry
-    from repro.optim import adamw
+    """A checkpointed CG solve stopped after two chunks and resumed from
+    its directory ends bitwise equal to one run straight through."""
+    from repro.resilience import runner
 
-    cfg = get_config("tinyllama-1.1b", reduced=True)
-    shape = ShapeConfig("t", 32, 4, "train")
-    pipe = make_pipeline(cfg, shape, seed=0)
-    opt = adamw(lr=1e-3)
+    rng = np.random.default_rng(0)
+    m = rng.standard_normal((64, 64)).astype(np.float32)
+    a = jnp.asarray(m @ m.T / 64 + 4 * np.eye(64, dtype=np.float32))
+    b = jnp.asarray(rng.standard_normal(64).astype(np.float32))
+    kw = dict(method="cg", tol=1e-6, every=4)
 
-    def step(params, state, t):
-        batch = {k: jnp.asarray(v) for k, v in
-                 pipe.global_batch_view(t).items()}
-        g = jax.grad(lambda p: registry.loss_fn(p, batch, cfg))(params)
-        return opt.update(g, state, params, jnp.asarray(t, jnp.int32))[:2]
+    straight = runner.checkpointed_solve(
+        a, b, directory=str(tmp_path / "straight"), maxiter=200, **kw)
+    stopped = runner.checkpointed_solve(
+        a, b, directory=str(tmp_path / "resumed"), maxiter=8, **kw)
+    assert int(stopped.iterations) == 8 and not bool(stopped.converged)
+    resumed = runner.checkpointed_solve(
+        a, b, directory=str(tmp_path / "resumed"), maxiter=200, **kw)
 
-    params = registry.init_params(cfg, jax.random.key(0))
-    state = opt.init(params)
-    # straight-through
-    pa, sa = params, state
-    for t in range(4):
-        pa, sa = step(pa, sa, t)
-    # interrupted at t=2
-    pb, sb = params, state
-    for t in range(2):
-        pb, sb = step(pb, sb, t)
-    mgr = CheckpointManager(str(tmp_path))
-    mgr.save(2, {"params": pb, "opt": sb}, blocking=True)
-    restored, _ = mgr.restore(
-        jax.eval_shape(lambda: {"params": pb, "opt": sb}))
-    pb, sb = restored["params"], restored["opt"]
-    for t in range(2, 4):
-        pb, sb = step(pb, sb, t)
-
-    for a, b in zip(jax.tree.leaves(pa), jax.tree.leaves(pb)):
-        np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                      np.asarray(b, np.float32))
+    assert resumed.info["resumed_from"] >= 8
+    assert bool(straight.converged) and bool(resumed.converged)
+    assert int(resumed.iterations) == int(straight.iterations)
+    np.testing.assert_array_equal(np.asarray(resumed.x),
+                                  np.asarray(straight.x))

@@ -1,7 +1,6 @@
-"""Production-mesh dry-run smoke: two representative cells + the paper's
-solver cell, each lowering + compiling on 512 virtual devices in a
-subprocess.  The full 40-cell sweep is run by ``repro.launch.dryrun --all``
-and recorded in EXPERIMENTS.md §Dry-run."""
+"""Production-mesh dry-run smoke: the solver cell lowers and compiles on
+512 virtual devices in a subprocess and writes its record where the caller
+says."""
 import json
 import os
 import subprocess
@@ -12,48 +11,35 @@ import pytest
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
-def _run(args, timeout=840):
+@pytest.fixture(scope="module")
+def solver_cell(tmp_path_factory):
+    """One dry-run of the CG solver cell on the pod mesh: its process and
+    the directory it wrote its record to."""
+    out = tmp_path_factory.mktemp("dryrun")
     env = dict(os.environ, PYTHONPATH=SRC)
     env.pop("XLA_FLAGS", None)      # dryrun.py sets its own device count
-    return subprocess.run(
-        [sys.executable, "-m", "repro.launch.dryrun", *args],
-        capture_output=True, text=True, env=env, timeout=timeout)
+    proc = subprocess.run(
+        [sys.executable, "-m", "repro.launch.dryrun", "--solver",
+         "--solver-method", "cg", "--mesh", "pod", "--out", str(out)],
+        capture_output=True, text=True, env=env, timeout=840)
+    return proc, out
 
 
 @pytest.mark.timeout(900)
-def test_dryrun_train_cell_single_pod():
-    proc = _run(["--arch", "qwen3-1.7b", "--shape", "train_4k",
-                 "--mesh", "pod"])
-    assert "all dry-run cells passed" in proc.stdout, proc.stdout[-2000:] \
-        + proc.stderr[-2000:]
-
-
-@pytest.mark.timeout(900)
-def test_dryrun_decode_cell_multipod():
-    proc = _run(["--arch", "mamba2-780m", "--shape", "long_500k",
-                 "--mesh", "multipod"])
-    assert "all dry-run cells passed" in proc.stdout, proc.stdout[-2000:] \
-        + proc.stderr[-2000:]
-
-
-@pytest.mark.timeout(900)
-def test_dryrun_solver_cell():
-    proc = _run(["--solver", "--solver-method", "cg", "--mesh", "pod"])
+def test_dryrun_solver_cell(solver_cell):
+    proc, _ = solver_cell
     assert "bottleneck=" in proc.stdout, proc.stdout[-2000:] \
         + proc.stderr[-2000:]
 
 
-def test_artifacts_have_roofline_fields():
-    d = os.path.join(os.path.dirname(__file__), "..", "experiments",
-                     "dryrun")
-    if not os.path.isdir(d) or not os.listdir(d):
-        pytest.skip("no dry-run artifacts yet")
-    for name in sorted(os.listdir(d))[:5]:
-        if not name.endswith(".json"):
-            continue
-        with open(os.path.join(d, name)) as f:
-            r = json.load(f)
-        rl = r["roofline"]
-        assert {"t_compute_s", "t_memory_s", "t_collective_s",
-                "bottleneck"} <= set(rl)
-        assert rl["bottleneck"] in ("compute", "memory", "collective")
+@pytest.mark.timeout(900)
+def test_artifacts_have_roofline_fields(solver_cell):
+    _, out = solver_cell
+    assert sorted(os.listdir(out)) == ["solver-cg__n61440__pod.json"]
+    with open(os.path.join(out, "solver-cg__n61440__pod.json")) as f:
+        r = json.load(f)
+    assert (r["method"], r["mesh"], r["chips"]) == ("cg", "pod", 256)
+    rl = r["roofline"]
+    assert {"t_compute_s", "t_memory_s", "t_collective_s",
+            "bottleneck"} <= set(rl)
+    assert rl["bottleneck"] in ("compute", "memory", "collective")

@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import attention as attn_k
 from repro.kernels import gemm, krylov_fused, ref, trsm
 
 
@@ -46,45 +45,6 @@ def test_trsm_sweep(n, m, sb, bc, unit):
     want = ref.trsm_lower(l, b, unit_diagonal=unit)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-3, atol=1e-3)
-
-
-@pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2), (8, 1)])
-@pytest.mark.parametrize("causal", [True, False])
-def test_attention_gqa_sweep(hq, hkv, causal):
-    k1, k2, k3 = jax.random.split(jax.random.key(4), 3)
-    q = jax.random.normal(k1, (2, hq, 256, 64), jnp.float32)
-    k = jax.random.normal(k2, (2, hkv, 256, 64), jnp.float32)
-    v = jax.random.normal(k3, (2, hkv, 256, 64), jnp.float32)
-    got = attn_k.flash_attention(q, k, v, causal=causal, bq=128, bk=128,
-                                 interpret=True)
-    want = ref.attention(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_attention_sliding_window():
-    k1, k2, k3 = jax.random.split(jax.random.key(5), 3)
-    q = jax.random.normal(k1, (1, 2, 256, 64), jnp.float32)
-    k = jax.random.normal(k2, (1, 2, 256, 64), jnp.float32)
-    v = jax.random.normal(k3, (1, 2, 256, 64), jnp.float32)
-    got = attn_k.flash_attention(q, k, v, causal=True, window=128,
-                                 bq=128, bk=128, interpret=True)
-    want = ref.attention(q, k, v, causal=True, window=128)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_attention_decode_offset():
-    """Tq < Tk (queries are the last positions — decode/chunked prefill)."""
-    k1, k2, k3 = jax.random.split(jax.random.key(6), 3)
-    q = jax.random.normal(k1, (1, 2, 128, 64), jnp.float32)
-    k = jax.random.normal(k2, (1, 2, 512, 64), jnp.float32)
-    v = jax.random.normal(k3, (1, 2, 512, 64), jnp.float32)
-    got = attn_k.flash_attention(q, k, v, causal=True, bq=128, bk=128,
-                                 interpret=True)
-    want = ref.attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("n", [1024, 4096, 128 * 6])

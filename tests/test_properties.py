@@ -1,5 +1,4 @@
 """Hypothesis property-based tests on the system's invariants."""
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -8,9 +7,6 @@ pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import lu as lu_mod
-from repro.data import TokenPipeline
-from repro.distributed import compression as C
-from repro.optim import wsd_schedule
 
 _settings = settings(max_examples=25, deadline=None)
 
@@ -33,102 +29,3 @@ def test_lu_factorization_property(n_blocks, bs, seed):
                                rtol=1e-3, atol=1e-2)
     # perm is a permutation
     assert sorted(np.asarray(perm).tolist()) == list(range(n))
-
-
-# --------------------------------------------------------------------------
-# data pipeline: shard decomposition == global view, for any shard count
-# --------------------------------------------------------------------------
-
-@_settings
-@given(num_shards=st.sampled_from([1, 2, 4, 8]), step=st.integers(0, 1 << 20),
-       seed=st.integers(0, 100))
-def test_pipeline_shard_property(num_shards, step, seed):
-    kw = dict(vocab_size=997, seq_len=32, global_batch=8, seed=seed)
-    full = TokenPipeline(**kw).global_batch_view(step)["tokens"]
-    parts = [TokenPipeline(**kw, num_shards=num_shards, shard=s).batch(step)
-             ["tokens"] for s in range(num_shards)]
-    np.testing.assert_array_equal(np.concatenate(parts, 0), full)
-
-
-# --------------------------------------------------------------------------
-# quantization: round-trip error bounded by half a block quant step
-# --------------------------------------------------------------------------
-
-@_settings
-@given(n=st.integers(1, 1024), scale=st.floats(1e-6, 1e6),
-       seed=st.integers(0, 1000))
-def test_quantize_property(n, scale, seed):
-    rng = np.random.default_rng(seed)
-    x = (rng.standard_normal(n) * scale).astype(np.float32)
-    q, s, m = C.quantize_int8(jnp.asarray(x))
-    back = np.asarray(C.dequantize_int8(q, s, m, (n,)))
-    pad = (-n) % C.BLOCK
-    xp = np.pad(x, (0, pad)).reshape(-1, C.BLOCK)
-    bound = np.repeat(np.abs(xp).max(1) / 127 * 0.51, C.BLOCK)[:n]
-    assert (np.abs(back - x) <= bound + 1e-12).all()
-
-
-# --------------------------------------------------------------------------
-# schedules: bounded, warmup-linear, non-negative
-# --------------------------------------------------------------------------
-
-@_settings
-@given(peak=st.floats(1e-5, 1.0), total=st.integers(10, 10_000),
-       step=st.integers(0, 10_000))
-def test_wsd_bounds_property(peak, total, step):
-    lr = wsd_schedule(peak, total, warmup_steps=max(total // 10, 1))
-    v = float(lr(min(step, total)))
-    assert 0.0 <= v <= peak * (1 + 1e-6)
-
-
-# --------------------------------------------------------------------------
-# attention: causal masking — future tokens never influence the past
-# --------------------------------------------------------------------------
-
-@_settings
-@given(seed=st.integers(0, 1000), t=st.sampled_from([8, 16]),
-       perturb_at=st.integers(1, 15))
-def test_causal_masking_property(seed, t, perturb_at):
-    from repro.kernels import ref
-    perturb_at = min(perturb_at, t - 1)
-    k1, k2, k3 = jax.random.split(jax.random.key(seed), 3)
-    q = jax.random.normal(k1, (1, 2, t, 16), jnp.float32)
-    k = jax.random.normal(k2, (1, 2, t, 16), jnp.float32)
-    v = jax.random.normal(k3, (1, 2, t, 16), jnp.float32)
-    base = ref.attention(q, k, v, causal=True)
-    k_mod = k.at[:, :, perturb_at:, :].add(100.0)
-    v_mod = v.at[:, :, perturb_at:, :].add(-50.0)
-    mod = ref.attention(q, k_mod, v_mod, causal=True)
-    np.testing.assert_allclose(np.asarray(base[:, :, :perturb_at]),
-                               np.asarray(mod[:, :, :perturb_at]),
-                               rtol=1e-5, atol=1e-5)
-
-
-# --------------------------------------------------------------------------
-# sharding rules: every spec is valid for its shape (divisibility)
-# --------------------------------------------------------------------------
-
-@_settings
-@given(arch=st.sampled_from(["qwen3-1.7b", "minicpm-2b", "hymba-1.5b",
-                             "kimi-k2-1t-a32b"]))
-def test_param_spec_divisibility_property(arch):
-    from repro.configs import get_config
-    from repro.train import sharding as sh
-    from repro.train import specs as sp
-    import jax.sharding as js
-
-    cfg = get_config(arch)
-    aparams = sp.abstract_params(cfg)
-    # a fake 16x16 mesh over 1 device via abstract check: use axis sizes
-    tp = 16
-    specs = jax.tree_util.tree_map_with_path(
-        lambda p, v: sh._param_rule(sh._path_str(p),
-                                    str(getattr(p[-1], "key", p[-1])),
-                                    v.shape, tp), aparams)
-    for (path, leaf), spec in zip(
-            jax.tree_util.tree_flatten_with_path(aparams)[0],
-            jax.tree.leaves(specs, is_leaf=lambda s: isinstance(
-                s, js.PartitionSpec))):
-        for dim, ax in enumerate(spec):
-            if ax is not None:
-                assert leaf.shape[dim] % tp == 0, (path, leaf.shape, spec)

@@ -25,17 +25,23 @@ def test_cg_newton_quadratic_one_shot():
     assert float(loss(new, None)) < 1e-8
 
 
-def test_cg_newton_on_tiny_lm():
-    import dataclasses
+def test_cg_newton_on_tiny_mlp():
+    """A two-layer tanh MLP (non-convex loss) in plain jnp, float32:
+    damping + backtracking make the step descend."""
+    kx, kt, k1, k2 = jax.random.split(jax.random.key(0), 4)
+    x = jax.random.normal(kx, (32, 8))
+    y = jnp.sin(x @ jax.random.normal(kt, (8, 1)))
+    params = {"w1": jax.random.normal(k1, (8, 16)) / jnp.sqrt(8.0),
+              "b1": jnp.zeros(16),
+              "w2": jax.random.normal(k2, (16, 1)) / jnp.sqrt(16.0),
+              "b2": jnp.zeros(1)}
 
-    from repro.configs import get_config
-    from repro.models import registry
+    def loss_fn(p, b):
+        xb, yb = b
+        h = jnp.tanh(xb @ p["w1"] + p["b1"])
+        return jnp.mean((h @ p["w2"] + p["b2"] - yb) ** 2)
 
-    cfg = dataclasses.replace(get_config("tinyllama-1.1b", reduced=True),
-                              param_dtype="float32", act_dtype="float32")
-    params = registry.init_params(cfg, jax.random.key(0))
-    batch = registry.make_batch(cfg, 2, 16)
-    loss_fn = lambda p, b: registry.loss_fn(p, b, cfg)
+    batch = (x, y)
     l0 = float(loss_fn(params, batch))
     # damping + backtracking = trust-region-flavored step: must not ascend
     new, aux = cg_newton_step(loss_fn, params, batch, damping=1.0,
@@ -44,3 +50,4 @@ def test_cg_newton_on_tiny_lm():
     assert np.isfinite(l1) and l1 < l0, (l0, l1)
     assert int(aux["cg_iters"]) >= 1
     assert float(aux["lr"]) > 0.0
+    assert jax.tree.structure(new) == jax.tree.structure(params)

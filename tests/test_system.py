@@ -1,39 +1,6 @@
-"""End-to-end behaviour: the training driver and the solver driver run to
-completion with loss decrease / small residual, and checkpoint-resume works
-through the real CLI path."""
-import os
-
-import numpy as np
-import pytest
-
+"""End-to-end behaviour: the solver driver runs to completion with a small
+residual for every method and in float64."""
 from repro.launch import solve as solve_cli
-from repro.launch import train as train_cli
-
-
-def test_train_driver_end_to_end(tmp_path):
-    losses = train_cli.main([
-        "--arch", "tinyllama-1.1b", "--reduced", "--steps", "8",
-        "--batch", "8", "--seq", "64", "--ckpt-dir", str(tmp_path),
-        "--ckpt-every", "4", "--lr", "3e-3"])
-    assert len(losses) == 8
-    assert losses[-1] < losses[0]
-    assert os.path.isdir(os.path.join(tmp_path, "step_8"))
-
-
-def test_train_driver_resumes(tmp_path):
-    args = ["--arch", "tinyllama-1.1b", "--reduced", "--steps", "6",
-            "--batch", "4", "--seq", "32", "--ckpt-dir", str(tmp_path),
-            "--ckpt-every", "3", "--lr", "1e-3"]
-    train_cli.main(args)                      # leaves step_6
-    more = train_cli.main([a if a != "6" else "9" for a in args])
-    assert len(more) == 3                     # resumed from 6, ran 6..9
-
-
-def test_train_driver_moe():
-    losses = train_cli.main([
-        "--arch", "dbrx-132b", "--reduced", "--steps", "5",
-        "--batch", "4", "--seq", "32", "--lr", "3e-3"])
-    assert losses[-1] < losses[0]
 
 
 def test_solve_driver_all_methods():
